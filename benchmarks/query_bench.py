@@ -134,12 +134,12 @@ def main(csv: CSV) -> None:
                 f"savings={srv.stats.query_dedup_savings[-1]:.2f}")
 
     if FAST:
-        # CI compile-check: force the Pallas kernel once in interpret mode
-        # so TPU-targeted code paths stay green on every push.
+        # CI compile-check: force the Pallas kernel once (interpreted off
+        # the TPU) so TPU-targeted code paths stay green on every push.
         us = jnp.asarray(rng.integers(0, N_USERS, 4).astype(np.int32))
         w, nbrs = _probe(state, us)
         out = knn_recommend_topn(state.ratings, w, nbrs, us, N_REC,
-                                 use_pallas=True, interpret=True)
+                                 use_pallas=True)
         jax.block_until_ready(out)
         csv.add("query_kernel_interpret_smoke", 0.0, "compiled=1")
 

@@ -25,9 +25,11 @@ from __future__ import annotations
 import argparse
 
 from benchmarks.common import CSV
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", choices=["twinsearch", "setsize", "scaling",
                                        "kernel", "maintenance",

@@ -58,8 +58,7 @@ def onboard_traditional(state: CFState, r0: jax.Array) -> CFState:
 
 
 def onboard_batch_traditional(state: CFState, R_new: jax.Array, *,
-                              fused: bool = True,
-                              interpret: bool = True) -> CFState:
+                              fused: bool = True) -> CFState:
     """k new users via the traditional path — the paper's O(k n m).
 
     ``fused=True`` (default) computes every burst user's similarities in a
@@ -88,7 +87,7 @@ def onboard_batch_traditional(state: CFState, R_new: jax.Array, *,
 
     # One (k, m) x (m, N) fused-epilogue matmul instead of k matvecs.
     S = cosine_similarity(R_new.astype(jnp.float32), ratings,
-                          new_norms, norms, interpret=interpret)
+                          new_norms, norms)
     cols = jnp.arange(N, dtype=jnp.int32)[None, :]
     seen = slot0 + jnp.arange(k, dtype=jnp.int32)[:, None]
     S = jnp.where(cols < seen, S, SENTINEL)              # per-step active set

@@ -16,6 +16,10 @@ import jax
 import jax.numpy as jnp
 
 EPS = 1e-12
+# TwinSearch matches similarities across lists to 1e-6, so every product
+# runs at f32 accuracy; the TPU's default matmul precision rounds f32
+# operands to bf16 (about 3 significant digits).
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def row_norms(R: jax.Array) -> jax.Array:
@@ -33,7 +37,7 @@ def _safe(x: jax.Array) -> jax.Array:
 def cosine_matrix(R: jax.Array, *, compute_dtype=jnp.float32) -> jax.Array:
     """(n, n) cosine similarity; fp32 accumulation."""
     Rn = R.astype(compute_dtype) / _safe(row_norms(R))[:, None].astype(compute_dtype)
-    return jnp.einsum("im,jm->ij", Rn, Rn,
+    return jnp.einsum("im,jm->ij", Rn, Rn, precision=HIGHEST,
                       preferred_element_type=jnp.float32)
 
 
@@ -45,7 +49,7 @@ def cosine_vs_all(R: jax.Array, norms: jax.Array, r0: jax.Array) -> jax.Array:
     """
     r0 = r0.astype(jnp.float32)
     dots = jnp.einsum("nm,m->n", R.astype(jnp.float32), r0,
-                      preferred_element_type=jnp.float32)
+                      precision=HIGHEST, preferred_element_type=jnp.float32)
     denom = _safe(norms) * _safe(jnp.linalg.norm(r0))
     return dots / denom
 

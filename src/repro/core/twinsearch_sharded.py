@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro._compat import shard_map
-
 from repro.core.types import CFState, OnboardStats, SENTINEL
 
 
@@ -191,7 +189,7 @@ def onboard_batch_sharded(state: CFState, R_new: jax.Array,
                  (P(None), P(None), P(None), P(None)))
     if maintain:
         out_specs = out_specs + ((rows, rows),)
-    out = shard_map(
+    out = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(rows, P(axes), rows, rows, P(None, None), P(None, None)),

@@ -13,6 +13,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.similarity import HIGHEST
 from repro.core.types import CFState, SENTINEL, active_mask
 
 
@@ -23,7 +24,8 @@ class SimCache(NamedTuple):
 
 def init_cache(ratings: jax.Array) -> SimCache:
     Rf = ratings.astype(jnp.float32)
-    return SimCache(dots=Rf @ Rf.T, sq=jnp.sum(jnp.square(Rf), axis=1))
+    return SimCache(dots=jnp.matmul(Rf, Rf.T, precision=HIGHEST),
+                    sq=jnp.sum(jnp.square(Rf), axis=1))
 
 
 def add_rating(state: CFState, cache: SimCache, user: jax.Array,

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _bag_kernel(idx_ref, w_ref, table_ref, o_ref):
     b = pl.program_id(0)
@@ -35,7 +33,7 @@ def _bag_kernel(idx_ref, w_ref, table_ref, o_ref):
 
 def embedding_bag_pallas(table: jax.Array, idx: jax.Array,
                          weights: jax.Array, *,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool) -> jax.Array:
     """table: (V, dim); idx: (n_bags, hot) int32; weights: (n_bags, hot)
     f32 (0 for padding slots).  Returns (n_bags, dim) weighted bag sums."""
     n_bags, hot = idx.shape
@@ -54,7 +52,7 @@ def embedding_bag_pallas(table: jax.Array, idx: jax.Array,
         _bag_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_bags, dim), table.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(idx, weights, table)

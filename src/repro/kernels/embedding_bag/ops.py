@@ -1,19 +1,17 @@
 """Jit'd wrapper for the EmbeddingBag kernel."""
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.embedding_bag.kernel import embedding_bag_pallas
+from repro.kernels.platform import on_tpu
 
 
-@partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def embedding_bag(table: jax.Array, idx: jax.Array,
                   weights: jax.Array | None = None,
-                  mask: jax.Array | None = None, *,
-                  interpret: bool = True) -> jax.Array:
+                  mask: jax.Array | None = None) -> jax.Array:
     """Sum-combiner EmbeddingBag: (V, dim) table, (n_bags, hot) indices,
     optional per-sample weights and validity mask -> (n_bags, dim)."""
     n_bags, hot = idx.shape
@@ -23,4 +21,4 @@ def embedding_bag(table: jax.Array, idx: jax.Array,
         weights = weights * mask.astype(weights.dtype)
     idx = jnp.clip(idx.astype(jnp.int32), 0, table.shape[0] - 1)
     return embedding_bag_pallas(table, idx, weights.astype(jnp.float32),
-                                interpret=interpret)
+                                interpret=not on_tpu())

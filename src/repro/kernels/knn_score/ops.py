@@ -3,7 +3,7 @@
 ``knn_scores`` routes to one of two equivalent backends:
 
   * ``use_pallas=True``  — the fused Pallas kernel (``kernel.py``;
-    ``interpret=True`` executes it on CPU, pass False on a real TPU),
+    compiled on a TPU, interpreted elsewhere, see ``kernels/platform.py``),
     which tiles the item axis and never materialises the (B, k, m)
     neighbour-ratings gather;
   * ``use_pallas=False`` — a ``lax.scan`` over the k neighbour slots
@@ -33,8 +33,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.knn_score.kernel import knn_scores_pallas
+from repro.kernels.knn_score.kernel import TILE, knn_scores_pallas
 from repro.kernels.knn_score.ref import EPS
+from repro.kernels.platform import on_tpu
 
 
 def _round_up(n: int, mult: int) -> int:
@@ -65,10 +66,10 @@ def _knn_scores_scan(ratings: jax.Array, w: jax.Array, nbrs: jax.Array,
     return jnp.where(ratings[users] != 0, -jnp.inf, scores)
 
 
-@partial(jax.jit, static_argnames=("use_pallas", "bm", "interpret"))
+@partial(jax.jit, static_argnames=("use_pallas", "bm"))
 def knn_scores(ratings: jax.Array, w: jax.Array, nbrs: jax.Array,
                users: jax.Array, *, use_pallas: bool | None = None,
-               bm: int = 512, interpret: bool = True) -> jax.Array:
+               bm: int = 512) -> jax.Array:
     """Batched kNN item scores from precomputed neighbour lists.
 
     Args:
@@ -88,28 +89,34 @@ def knn_scores(ratings: jax.Array, w: jax.Array, nbrs: jax.Array,
     users = jnp.clip(users.astype(jnp.int32), 0, N - 1)
 
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+        use_pallas = on_tpu()
     if not use_pallas:
         return _knn_scores_scan(ratings, w, nbrs, users)
 
     # Item columns pad to the block multiple with zeros: a padded column
     # scores 0/EPS = 0 and is never "seen", so it survives to the slice
-    # below but no further (callers slice before any top-n).
+    # below but no further (callers slice before any top-n).  Arena rows
+    # pad to the tile (never referenced); batch rows pad with zero weights
+    # and are sliced away too.
     bm = min(bm, _round_up(m, 128))
     mp = _round_up(m, bm)
-    rp = jnp.pad(ratings, ((0, 0), (0, mp - m)))
-    out = knn_scores_pallas(rp, w, nbrs, users, bm=bm, interpret=interpret)
-    return out[:, :m]
+    Np, Bp = _round_up(N, TILE), _round_up(B, TILE)
+    rp = jnp.pad(ratings, ((0, Np - N), (0, mp - m)))
+    out = knn_scores_pallas(
+        rp, jnp.pad(w, ((0, Bp - B), (0, 0))),
+        jnp.pad(nbrs, ((0, Bp - B), (0, 0))),
+        jnp.pad(rp[users], ((0, Bp - B), (0, 0))),
+        bm=bm, interpret=not on_tpu())
+    return out[:B, :m]
 
 
-@partial(jax.jit, static_argnames=("n_rec", "use_pallas", "bm", "interpret"))
+@partial(jax.jit, static_argnames=("n_rec", "use_pallas", "bm"))
 def knn_recommend_topn(ratings: jax.Array, w: jax.Array, nbrs: jax.Array,
                        users: jax.Array, n_rec: int = 10, *,
-                       use_pallas: bool | None = None, bm: int = 512,
-                       interpret: bool = True
+                       use_pallas: bool | None = None, bm: int = 512
                        ) -> tuple[jax.Array, jax.Array]:
     """Full fused read path: scores + top-``n_rec`` unseen items.
     Returns ((B, n_rec) scores, (B, n_rec) item ids)."""
     scores = knn_scores(ratings, w, nbrs, users, use_pallas=use_pallas,
-                        bm=bm, interpret=interpret)
+                        bm=bm)
     return jax.lax.top_k(scores, n_rec)

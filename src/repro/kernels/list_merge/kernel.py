@@ -11,8 +11,9 @@ pre-sorted inserts in a single pass, replacing k sequential shift-gathers
                      k smallest being dropped);
   3. gather:         out[j] = row[j + k − b(j)] or, when an insert's rank
                      equals j + k, ins[b(j)].  The data-dependent offset
-                     k − b(j) ∈ [0, k] is resolved as k + 1 static shifted
-                     selects, so the kernel needs no in-VMEM gather.
+                     k − b(j) ∈ [0, k] is resolved as k + 1 selects
+                     against the row rotated one lane further each time,
+                     so the kernel needs no in-VMEM gather.
 
 Work per row is O(L·k) compares/selects on the VPU, all on (br, LP)
 blocks; the inputs stream HBM -> VMEM once, totalling O(N·(N + k)) for the
@@ -31,16 +32,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 from repro.kernels.list_merge.ref import POS_INF
 
-
-def _shift_left(x: jax.Array, d: int) -> jax.Array:
-    """x[:, j + d] with wrap-around; callers only select j + d < LP."""
-    if d == 0:
-        return x
-    return jnp.concatenate([x[:, d:], x[:, :d]], axis=1)
+# The kernel keeps about sixteen (br, LP) 32-bit blocks in VMEM, ~0.5 KiB
+# per list column at br=8.  Mosaic's default 16 MiB scope stops that near
+# L = 30k; with half of a v5e core's 128 MiB it compiles at k = 64 up to
+# L = 122,000 (not 125,000), past any dense (N, N) arena that fits the
+# chip's 16 GB of HBM.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
 def _merge_kernel(vals_ref, idx_ref, iv_ref, ii_ref, ov_ref, oi_ref, *,
@@ -51,43 +50,63 @@ def _merge_kernel(vals_ref, idx_ref, iv_ref, ii_ref, ov_ref, oi_ref, *,
     si = ii_ref[...]                                 # (br, kp) int32
     br, LP = v.shape
 
-    # 1. insert ranks: rank_t = #{row entries <= s_t} + t.  Row entries tie-
-    # break before inserts (side="right"); among equal inserts the +t term
-    # preserves burst order.  POS_INF column pads never count.
-    ranks = []
-    for t in range(kp):
-        p = jnp.sum((v <= sv[:, t:t + 1]).astype(jnp.int32), axis=1,
-                    keepdims=True)                   # (br, 1)
-        ranks.append(p + t)
-
-    # 2. merge path: output slot j holds merged rank j + kp (first kp
-    # dropped); b(j) inserts precede it, and it IS insert b(j) iff some
-    # rank_t == j + kp (ranks are strictly increasing in t).
+    # 1+2. insert ranks and merge path.  rank_t = #{row entries <= s_t} + t:
+    # row entries tie-break before inserts (side="right"), among equal
+    # inserts the +t term preserves burst order, POS_INF pads never count.
+    # Output slot j holds merged rank j + kp (first kp dropped); b(j)
+    # inserts precede it, and it IS insert b(j) iff some rank_t == j + kp
+    # (ranks are strictly increasing in t).
+    #
+    # All three loops are ``fori_loop``s over (br, LP) carries, so VMEM
+    # holds a fixed handful of blocks whatever kp is.  Column t of the
+    # inserts is taken with a masked sum (one value plus exact zeros).
     tgt = jax.lax.broadcasted_iota(jnp.int32, (br, LP), 1) + kp
-    b = jnp.zeros((br, LP), jnp.int32)
-    is_ins = jnp.zeros((br, LP), jnp.bool_)
-    for t in range(kp):
-        b += (ranks[t] < tgt).astype(jnp.int32)
-        is_ins |= ranks[t] == tgt
+    lane = jax.lax.broadcasted_iota(jnp.int32, (br, kp), 1)
 
-    # 3. gather via static shifted selects: row part reads row[j + kp - b].
-    out_v = jnp.zeros((br, LP), v.dtype)
-    out_i = jnp.zeros((br, LP), ids.dtype)
-    for d in range(kp + 1):
+    def column(x, t):
+        return jnp.sum(jnp.where(lane == t, x, jnp.zeros_like(x)), axis=1,
+                       keepdims=True)                # (br, 1)
+
+    def count(t, carry):
+        b, hit = carry
+        rank = jnp.sum((v <= column(sv, t)).astype(jnp.int32), axis=1,
+                       keepdims=True) + t            # (br, 1)
+        return (b + (rank < tgt).astype(jnp.int32),
+                hit + (rank == tgt).astype(jnp.int32))
+
+    zeros = jnp.zeros((br, LP), jnp.int32)
+    b, hit = jax.lax.fori_loop(0, kp, count, (zeros, zeros))
+    is_ins = hit > 0
+
+    # 3. gather: the row part of slot j reads row[j + d] with d = kp - b(j).
+    # Walk d = 0..kp with one lane rotation per step; wrap-around lanes are
+    # never selected, since j + d < LP wherever b(j) = kp - d.
+    def shift(d, carry):
+        rv, ri, out_v, out_i = carry
         sel = jnp.logical_not(is_ins) & (b == kp - d)
-        out_v = jnp.where(sel, _shift_left(v, d), out_v)
-        out_i = jnp.where(sel, _shift_left(ids, d), out_i)
-    for t in range(kp):
+        return (pltpu.roll(rv, LP - 1, 1),           # rv[:, j] <- rv[:, j+1]
+                pltpu.roll(ri, LP - 1, 1),
+                jnp.where(sel, rv, out_v), jnp.where(sel, ri, out_i))
+
+    _, _, out_v, out_i = jax.lax.fori_loop(
+        0, kp + 1, shift,
+        (v, ids, jnp.zeros((br, LP), v.dtype), jnp.zeros((br, LP),
+                                                         ids.dtype)))
+
+    def place(t, carry):
+        out_v, out_i = carry
         sel = is_ins & (b == t)
-        out_v = jnp.where(sel, sv[:, t:t + 1], out_v)
-        out_i = jnp.where(sel, si[:, t:t + 1], out_i)
+        return (jnp.where(sel, column(sv, t), out_v),
+                jnp.where(sel, column(si, t), out_i))
+
+    out_v, out_i = jax.lax.fori_loop(0, kp, place, (out_v, out_i))
     ov_ref[...] = out_v
     oi_ref[...] = out_i
 
 
 def merge_insert_pallas(vals: jax.Array, idx: jax.Array,
                         ins_vals: jax.Array, ins_idx: jax.Array, *,
-                        br: int = 8, interpret: bool = True
+                        br: int = 8, interpret: bool
                         ) -> tuple[jax.Array, jax.Array]:
     """(R, LP) padded lists + (R, kp) sorted gated inserts -> merged (R, LP).
 
@@ -118,7 +137,8 @@ def merge_insert_pallas(vals: jax.Array, idx: jax.Array,
             jax.ShapeDtypeStruct((R, LP), vals.dtype),
             jax.ShapeDtypeStruct((R, LP), jnp.int32),
         ),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(vals, idx, ins_vals, ins_idx)

@@ -5,7 +5,7 @@ NEG_INF gating of masked lanes, POS_INF column padding) and routes to one
 of two equivalent backends:
 
   * ``use_pallas=True``  — the fused Pallas kernel (``kernel.py``;
-    ``interpret=True`` executes it on CPU, pass False on a real TPU);
+    compiled on a TPU, interpreted elsewhere, see ``kernels/platform.py``);
   * ``use_pallas=False`` — a pure-XLA merge: two ``searchsorted`` rank
     computations plus one scatter, O(R·(L + k)) data movement.
 
@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from repro.kernels.list_merge.kernel import merge_insert_pallas
 from repro.kernels.list_merge.ref import NEG_INF, POS_INF
+from repro.kernels.platform import on_tpu
 
 
 def _round_up(n: int, mult: int) -> int:
@@ -67,11 +68,11 @@ def _merge_xla(vals: jax.Array, idx: jax.Array, sv: jax.Array,
     return out_v, out_i
 
 
-@partial(jax.jit, static_argnames=("use_pallas", "br", "interpret"))
+@partial(jax.jit, static_argnames=("use_pallas", "br"))
 def merge_insert(vals: jax.Array, idx: jax.Array, ins_vals: jax.Array,
                  ins_idx: jax.Array, ins_mask: jax.Array | None = None, *,
-                 use_pallas: bool | None = None, br: int = 8,
-                 interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                 use_pallas: bool | None = None, br: int = 8
+                 ) -> tuple[jax.Array, jax.Array]:
     """Merge k (value, index) inserts into each of R ascending lists.
 
     Args:
@@ -99,7 +100,7 @@ def merge_insert(vals: jax.Array, idx: jax.Array, ins_vals: jax.Array,
         ins_mask = jnp.broadcast_to(ins_mask, (R, k))
 
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+        use_pallas = on_tpu()
     if not use_pallas:
         sv, si = _sort_inserts(ins_vals, ins_idx, ins_mask)
         return _merge_xla(vals, idx, sv, si)
@@ -119,5 +120,5 @@ def merge_insert(vals: jax.Array, idx: jax.Array, ins_vals: jax.Array,
                  constant_values=float(POS_INF))
     ip = jnp.pad(idx, ((0, Rp - R), (0, LP - L)))
     out_v, out_i = merge_insert_pallas(vp, ip, sv, si, br=br,
-                                       interpret=interpret)
+                                       interpret=not on_tpu())
     return out_v[:R, :L], out_i[:R, :L]

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 EPS = 1e-12
 
 
@@ -41,18 +39,19 @@ def _sim_kernel(qn_ref, rn_ref, q_ref, r_ref, o_ref, acc_ref, *, nk: int):
 
     @pl.when(k == nk - 1)
     def _epilogue():
-        denom = jnp.maximum(
-            qn_ref[...][:, None] * rn_ref[...][None, :], EPS)
+        denom = jnp.maximum(qn_ref[...] * rn_ref[...], EPS)   # (bq, bn)
         o_ref[...] = acc_ref[...] / denom
 
 
 def similarity_pallas(Q: jax.Array, R: jax.Array, q_norms: jax.Array,
                       r_norms: jax.Array, *, bq: int = 128, bn: int = 256,
-                      bk: int = 512, interpret: bool = True) -> jax.Array:
+                      bk: int = 512, interpret: bool) -> jax.Array:
     """(nq, m), (n, m) -> (nq, n) cosine similarity, fp32.
 
     Dimensions must be pre-padded to the block multiples (``ops.py`` does
     this); zero-padded rows produce sim 0 via the EPS-guarded denominator.
+    The norms travel as a (nq, 1) column and a (1, n) row: 1-D norm
+    blocks get an XLA layout that Mosaic refuses.
     """
     nq, m = Q.shape
     n, m2 = R.shape
@@ -66,15 +65,15 @@ def similarity_pallas(Q: jax.Array, R: jax.Array, q_norms: jax.Array,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bq,), lambda i, j, k: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            pl.BlockSpec((bq, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
             pl.BlockSpec((bq, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bn, bk), lambda i, j, k: (j, k)),
         ],
         out_specs=pl.BlockSpec((bq, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nq, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q_norms, r_norms, Q, R)
+    )(q_norms.reshape(nq, 1), r_norms.reshape(1, n), Q, R)

@@ -1,7 +1,7 @@
 """Jit'd public wrapper: pad to block multiples, run the kernel, slice.
 
-``interpret=True`` executes the kernel body on CPU (this container);
-on a real TPU pass ``interpret=False``.
+The kernel compiles on a TPU and is interpreted elsewhere
+(``kernels/platform.py``).
 """
 from __future__ import annotations
 
@@ -10,6 +10,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.platform import on_tpu
 from repro.kernels.similarity.kernel import similarity_pallas
 from repro.kernels.similarity.ref import EPS
 
@@ -24,12 +25,12 @@ def _pad(x: jax.Array, mult: int, axis: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-@partial(jax.jit, static_argnames=("bq", "bn", "bk", "interpret"))
+@partial(jax.jit, static_argnames=("bq", "bn", "bk"))
 def cosine_similarity(Q: jax.Array, R: jax.Array,
                       q_norms: jax.Array | None = None,
                       r_norms: jax.Array | None = None, *,
-                      bq: int = 128, bn: int = 256, bk: int = 512,
-                      interpret: bool = True) -> jax.Array:
+                      bq: int = 128, bn: int = 256, bk: int = 512
+                      ) -> jax.Array:
     """Cosine similarity of each row of Q against each row of R — the
     traditional-path hot loop, on the Pallas kernel."""
     if q_norms is None:
@@ -42,5 +43,5 @@ def cosine_similarity(Q: jax.Array, R: jax.Array,
     qn = jnp.maximum(_pad(q_norms.astype(jnp.float32), bq, 0), EPS)
     rn = jnp.maximum(_pad(r_norms.astype(jnp.float32), bn, 0), EPS)
     out = similarity_pallas(Qp, Rp, qn, rn, bq=bq, bn=bn, bk=bk,
-                            interpret=interpret)
+                            interpret=not on_tpu())
     return out[:nq, :n]

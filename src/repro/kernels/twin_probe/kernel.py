@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _make_kernel(tol: float):
     def kernel(rows_ref, s0_ref, mask_ref, count_ref):
@@ -34,7 +32,7 @@ def _make_kernel(tol: float):
 
 def twin_probe_pallas(probe_rows: jax.Array, sims0: jax.Array,
                       tol: float = 1e-6, *, bn: int = 512,
-                      interpret: bool = True
+                      interpret: bool
                       ) -> tuple[jax.Array, jax.Array]:
     """probe_rows: (c, N) unsorted probe similarity rows; sims0: (c,).
     Returns (mask (N, 1) bool, per-block counts (N/bn, 1) int32)."""
@@ -56,7 +54,7 @@ def twin_probe_pallas(probe_rows: jax.Array, sims0: jax.Array,
             jax.ShapeDtypeStruct((N, 1), jnp.bool_),
             jax.ShapeDtypeStruct((N // bn, 1), jnp.int32),
         ),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(probe_rows, sims0[:, None])

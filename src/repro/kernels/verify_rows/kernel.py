@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _verify_kernel(c_ref, r0_ref, valid_ref, o_ref, acc_ref, *, nk: int):
     k = pl.program_id(1)
@@ -37,7 +35,7 @@ def _verify_kernel(c_ref, r0_ref, valid_ref, o_ref, acc_ref, *, nk: int):
 
 def verify_rows_pallas(C: jax.Array, r0: jax.Array, valid: jax.Array, *,
                        bs: int = 256, bk: int = 512,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """C: (s, m) candidate rows; r0: (m,); valid: (s,) bool.
     Returns (s, 1) bool — row i equals r0 and is a live candidate."""
     s, m = C.shape
@@ -55,7 +53,7 @@ def verify_rows_pallas(C: jax.Array, r0: jax.Array, valid: jax.Array, *,
         out_specs=pl.BlockSpec((bs, 1), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((s, 1), jnp.bool_),
         scratch_shapes=[pltpu.VMEM((bs, 1), jnp.bool_)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(C, r0, valid)

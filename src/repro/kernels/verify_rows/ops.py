@@ -6,6 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.platform import on_tpu
 from repro.kernels.verify_rows.kernel import verify_rows_pallas
 
 
@@ -39,10 +40,9 @@ def arena_healthy(sim_vals: jax.Array, ratings: jax.Array,
     return lists_ok & ratings_ok & norms_ok & n_ok
 
 
-@partial(jax.jit, static_argnames=("bs", "bk", "interpret"))
+@partial(jax.jit, static_argnames=("bs", "bk"))
 def verify_rows(C: jax.Array, r0: jax.Array, valid: jax.Array, *,
-                bs: int = 256, bk: int = 512,
-                interpret: bool = True) -> jax.Array:
+                bs: int = 256, bk: int = 512) -> jax.Array:
     """(s, m) candidates vs (m,) target -> (s,) bool verified-twin flags."""
     s, m = C.shape
     ps, pk = (-s) % bs, (-m) % bk
@@ -51,5 +51,6 @@ def verify_rows(C: jax.Array, r0: jax.Array, valid: jax.Array, *,
     # matching C's zero padding, so equality is preserved.
     r0p = jnp.pad(r0, (0, pk))
     vp = jnp.pad(valid, (0, ps))            # padded rows -> invalid
-    out = verify_rows_pallas(Cp, r0p, vp, bs=bs, bk=bk, interpret=interpret)
+    out = verify_rows_pallas(Cp, r0p, vp, bs=bs, bk=bk,
+                             interpret=not on_tpu())
     return out[:s, 0]

@@ -11,11 +11,8 @@ import jax
 
 
 def _mk(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:             # pre-0.5 jax: Auto is the only mode
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

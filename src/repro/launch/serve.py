@@ -11,6 +11,8 @@ import logging
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 log = logging.getLogger("repro.launch.serve")
 
 
@@ -62,6 +64,7 @@ def main() -> None:
     ap.add_argument("--n-new", type=int, default=8)
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
     (serve_cf if args.service == "cf" else serve_lm)(args)
 
 

@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro._compat import shard_map
-
 from repro.configs.base import GNNConfig
 
 
@@ -92,7 +90,7 @@ def forward_segment_ep(params: dict, feats: jax.Array, edge_src: jax.Array,
         h = jax.nn.elu(layer(feats, p["l1"], True))
         return layer(h, p["l2"], False)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=info.mesh,
         in_specs=(P(None, None), P(info.axes), P(info.axes),

@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro._compat import shard_map
-
 from repro.configs.base import MoEConfig
 
 
@@ -129,7 +127,7 @@ def moe_ffn_ep(x: jax.Array, router_w: jax.Array, w_in: jax.Array,
         aux = E * jnp.sum((frac / (k * t_tot)) * (prob / t_tot))
         return y, aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn,
         mesh=info.mesh,
         in_specs=(info.acts_spec, P(None, None), info.win_spec,

@@ -1,8 +1,11 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode."""
+"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpreted on
+the CPU; ``test_tpu_compile.py`` compiles the main-path kernels for a
+v5e)."""
 from __future__ import annotations
 
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 import jax.numpy as jnp
 
@@ -15,7 +18,6 @@ from repro.kernels.list_merge.ref import merge_insert_ref
 from repro.kernels.similarity.ref import similarity_ref
 from repro.kernels.twin_probe.ref import twin_probe_ref
 from repro.kernels.verify_rows.ref import verify_rows_ref
-from tests.hypcompat import given, settings, st
 
 
 @pytest.mark.parametrize("nq,n,m", [(8, 16, 32), (37, 451, 300),

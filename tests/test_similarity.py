@@ -2,7 +2,7 @@
 from __future__ import annotations
 
 import numpy as np
-from tests.hypcompat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax.numpy as jnp
 

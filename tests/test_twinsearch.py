@@ -7,7 +7,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from tests.hypcompat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (build_state, make_probes, onboard_batch,
                         onboard_batch_traditional, set0_cap,

@@ -94,9 +94,9 @@ the refusal dance.  Before dispatch, **twin-query dedup**
 neighbour sims + ids and, for recommendations, the user's own rating
 row — are bitwise identical: the paper's twins share similarity lists,
 so they provably share recommendation scores, and only the unique rows
-are scored (``ServerStats.query_dedup_savings``).  Unique-row and batch
-shapes are bucketed to powers of two so the jitted query programs are
-compile-once per bucket, and each batch pays exactly two host transfers
+are scored (``ServerStats.queries`` / ``query_unique``).  Unique-row and
+batch shapes are bucketed to powers of two so the jitted query programs
+are compile-once per bucket, and each batch pays exactly two host transfers
 (the probe for dedup keys, the fanned-out results).
 
 State is the fixed-capacity ``CFState`` (jit-friendly); all mutating ops
@@ -132,6 +132,7 @@ from repro.kernels.verify_rows.ops import arena_healthy
 from repro.serving import guard
 from repro.serving.dedup import dedup_rows
 from repro.serving.config import ServerConfig
+from repro.serving.tracing import span
 from repro.serving.wal import WriteAheadLog
 from repro.training import checkpoint
 from repro.training.elastic import Action, StragglerMonitor
@@ -189,7 +190,6 @@ class ServerStats:
     rotation_ms: deque = field(init=False)
     rotation_pause_ms: deque = field(init=False)
     query_ms: deque = field(init=False)
-    query_dedup_savings: deque = field(init=False)
 
     def __post_init__(self) -> None:
         # Fixed-size ring buffers: sustained traffic must not grow host
@@ -199,10 +199,8 @@ class ServerStats:
         # What rotation actually cost a *single request*: the synchronous
         # stall (full rotation, or just the final swap when incremental).
         self.rotation_pause_ms = deque(maxlen=64)
-        # Per-batch query latency + twin-dedup savings fraction (the
-        # trailing-window view; queries/query_unique are the totals).
+        # Per-batch query latency (the trailing-window view).
         self.query_ms = deque(maxlen=self.latency_window)
-        self.query_dedup_savings = deque(maxlen=self.latency_window)
 
     def summary(self) -> dict:
         ms = sorted(self.onboard_ms) or [0.0]
@@ -333,10 +331,13 @@ class CFServer:
         self.rating_range = (float(config.rating_range[0]),
                              float(config.rating_range[1]))
         self.rotate_headroom = float(config.rotation.headroom)
-        self.state: CFState = jax.jit(
-            lambda R: build_state(R, capacity_extra=config.capacity_extra,
-                                  measure=config.measure))(jnp.asarray(
-                                      ratings, jnp.float32))
+
+        def build_arena(R):
+            return build_state(R, capacity_extra=config.capacity_extra,
+                               measure=config.measure)
+
+        self.state: CFState = jax.jit(build_arena)(
+            jnp.asarray(ratings, jnp.float32))
         self._key = jax.random.PRNGKey(config.seed)
         self.stats = ServerStats(latency_window=config.latency_window)
         self.quarantine = guard.Quarantine(
@@ -417,12 +418,17 @@ class CFServer:
         """(Re)wrap the jitted ops for the *current* arena geometry.
         Called at construction and after every rotation/rollback/restore —
         the closures capture ``n_base``/``s_max``/``k_cap``, which those
-        transitions change."""
+        transitions change.  Each program is a named function, so the
+        device trace names it (``jit_onboard_twinsearch``, ...)."""
         self.s_max = set0_cap(self.n_base)
         n_base, k_cap = self.n_base, self.k_cap
-        self._onboard = jax.jit(lambda st, r0, probes: ts.onboard_twinsearch(
-            st, r0, probes, s_max=self.s_max, n_base=n_base,
-            k_cap=k_cap, tol=self.tol))
+
+        def onboard_twinsearch(st, r0, probes):
+            return ts.onboard_twinsearch(st, r0, probes, s_max=self.s_max,
+                                         n_base=n_base, k_cap=k_cap,
+                                         tol=self.tol)
+
+        self._onboard = jax.jit(onboard_twinsearch)
         self._onboard_trad = jax.jit(base_lib.onboard_traditional)
         self._recommend = jax.jit(knn.recommend,
                                   static_argnames=("k_neighbors", "n_rec"))
@@ -434,17 +440,18 @@ class CFServer:
         # the deduped rows through the fused scoring kernel and cuts top-n
         # on device, so results come back in one more transfer.  k / n_rec
         # are static; batch shapes are pow2-bucketed by the endpoints.
-        self._probe_rec = jax.jit(
-            lambda st, users, k: (
-                *knn.top_k_neighbors_batch(st, users, k),
-                st.ratings[users]),
-            static_argnames=("k",))
+        def probe_recommend(st, users, k):
+            return (*knn.top_k_neighbors_batch(st, users, k),
+                    st.ratings[users])
+
+        def score_recommend(st, sims, nbrs, users, n_rec):
+            return knn_recommend_topn(st.ratings, jnp.maximum(sims, 0.0),
+                                      nbrs, users, n_rec)
+
+        self._probe_rec = jax.jit(probe_recommend, static_argnames=("k",))
         self._probe_topk = jax.jit(knn.top_k_neighbors_batch,
                                    static_argnames=("k",))
-        self._score_rec = jax.jit(
-            lambda st, sims, nbrs, users, n_rec: knn_recommend_topn(
-                st.ratings, jnp.maximum(sims, 0.0), nbrs, users, n_rec),
-            static_argnames=("n_rec",))
+        self._score_rec = jax.jit(score_recommend, static_argnames=("n_rec",))
         self._score_pred = jax.jit(
             jax.vmap(knn.predict_from_neighbors, in_axes=(None, 0, 0, 0)))
         self._init_cache = jax.jit(upd_lib.init_cache)
@@ -499,8 +506,11 @@ class CFServer:
         self._replay_add_chunk = jax.jit(_chunk_add)
         # key_{i+1} = split(key_i)[0], n times in one dispatch — the same
         # chain the live path walks one split per twin-search onboard
-        self._advance_key = jax.jit(lambda key, m: jax.lax.fori_loop(
-            0, m, lambda _, k: jax.random.split(k)[0], key))
+        def advance_key(key, m):
+            return jax.lax.fori_loop(
+                0, m, lambda _, k: jax.random.split(k)[0], key)
+
+        self._advance_key = jax.jit(advance_key)
 
     def _reject(self, kind: str, reason: str, payload=None,
                 detail: str = "") -> dict:
@@ -722,7 +732,8 @@ class CFServer:
         op is applied — the write-ahead contract."""
         self._seq += 1
         if self.wal is not None and not self._replaying:
-            self.wal.append(self._seq, op, fields, arrays)
+            with span("cf.wal.append"):
+                self.wal.append(self._seq, op, fields, arrays)
             self.stats.wal_appends += 1
         return self._seq
 
@@ -980,18 +991,21 @@ class CFServer:
 
     # -- health check + snapshot cadence ------------------------------------
 
+    def _is_healthy(self, st: CFState) -> bool:
+        """The arena invariant sweep over ``st``, synced to the host."""
+        with span("cf.health_check"):
+            return bool(self._healthy(st.sim_vals, st.ratings, st.norms,
+                                      st.n_active))
+
     def _state_ok(self) -> bool:
         """Verify the arena invariant; heal poisoned rows from replicas
         (exact, similarity-free) when possible, roll back to the last good
         snapshot otherwise.  False iff a rollback happened."""
-        if bool(self._healthy(self.state.sim_vals, self.state.ratings,
-                              self.state.norms, self.state.n_active)):
+        if self._is_healthy(self.state):
             return True
         if self.replicas is not None:
             fixed, rows = self.replicas.repair(self.state)
-            if fixed is not None and bool(self._healthy(
-                    fixed.sim_vals, fixed.ratings, fixed.norms,
-                    fixed.n_active)):
+            if fixed is not None and self._is_healthy(fixed):
                 self.state = fixed
                 self._cache = None
                 self.stats.repairs += 1
@@ -1016,8 +1030,7 @@ class CFServer:
         if self._since_snapshot >= self.snapshot_every:
             # Never snapshot unverified state: a snapshot of a poisoned
             # arena would poison every future rollback.
-            if bool(self._healthy(self.state.sim_vals, self.state.ratings,
-                                  self.state.norms, self.state.n_active)):
+            if self._is_healthy(self.state):
                 self._take_snapshot()
         return True
 
@@ -1065,13 +1078,14 @@ class CFServer:
         rotated = False
         if int(self.state.n_active) >= self.state.capacity:
             rotated = True
-            if self._rcfg.budget_rows > 0:
-                # The plan didn't finish (or start) in time: drain it now.
-                self._force_drain()
-            else:
-                self._log("rotate")
-                self._crashpoint("rotate.post_wal")
-                self._rotate()
+            with span("cf.onboard.rotate"):
+                if self._rcfg.budget_rows > 0:
+                    # The plan didn't finish (or start) in time: drain it.
+                    self._force_drain()
+                else:
+                    self._log("rotate")
+                    self._crashpoint("rotate.post_wal")
+                    self._rotate()
 
         r0_np = np.asarray(ratings, dtype=np.float32)
         r0 = jnp.asarray(r0_np)
@@ -1102,8 +1116,9 @@ class CFServer:
         self.monitor.step_started()
         t0 = time.perf_counter()
         try:
-            (new_state, found, overflowed), retries = guard.call_with_retry(
-                run, self.retry)
+            with span("cf.onboard.run"):
+                (new_state, found, overflowed), retries = \
+                    guard.call_with_retry(run, self.retry)
         except Exception as e:          # noqa: BLE001 — contract: no raise
             self.monitor.step_finished()
             self.stats.errors += 1
@@ -1164,13 +1179,12 @@ class CFServer:
             self._replication_tick()
             self._state_ok()
 
-    def _note_query_batch(self, n_valid: int, n_unique: int, savings: float,
-                          dt_ms: float, degraded: bool) -> None:
+    def _note_query_batch(self, n_valid: int, n_unique: int, dt_ms: float,
+                          degraded: bool) -> None:
         self.stats.query_batches += 1
         self.stats.queries += n_valid
         self.stats.query_unique += n_unique
         self.stats.query_ms.append(dt_ms)
-        self.stats.query_dedup_savings.append(savings)
         if degraded:
             self.stats.query_degraded += n_valid
 
@@ -1194,8 +1208,10 @@ class CFServer:
         keys are bitwise identical are scored once and fanned out."""
         users = list(users)
         results: list[list[tuple[int, float]]] = [[] for _ in users]
-        valid = [i for i, u in enumerate(users)
-                 if not (guard.validate_user_id(u, int(self.state.n_active))
+        with span("cf.read.validate"):
+            valid = [i for i, u in enumerate(users)
+                     if not (guard.validate_user_id(
+                         u, int(self.state.n_active))
                          and self._reject("recommend", guard.R_USER_ID, u))]
         if not valid:
             return results
@@ -1203,29 +1219,34 @@ class CFServer:
         k_eff = self._query_k(k_neighbors)
         t0 = time.perf_counter()
 
-        uvec = np.asarray([int(users[i]) for i in valid], np.int32)
-        sims, nbrs, rows = jax.device_get(self._probe_rec(
-            self.state, jnp.asarray(self._pad_bucket(uvec)), k_eff))
+        with span("cf.read.probe"):
+            uvec = np.asarray([int(users[i]) for i in valid], np.int32)
+            sims, nbrs, rows = jax.device_get(self._probe_rec(
+                self.state, jnp.asarray(self._pad_bucket(uvec)), k_eff))
         B = len(uvec)
         sims, nbrs, rows = sims[:B], nbrs[:B], rows[:B]
 
         # Twin dedup (probe -> exact verify): the scoring kernel is a
         # deterministic function of exactly (sims, nbrs, own row), so
         # bitwise-equal keys provably share scores.
-        keys = np.concatenate([sims.view(np.uint32), nbrs.view(np.uint32),
-                               rows.view(np.uint32)], axis=1)
-        plan = dedup_rows(keys)
-        sel = self._pad_bucket(plan.unique_rows)
-        scores, items = jax.device_get(self._score_rec(
-            self.state, jnp.asarray(sims[sel]), jnp.asarray(nbrs[sel]),
-            jnp.asarray(uvec[sel]), n))
+        with span("cf.read.dedup"):
+            keys = np.concatenate([sims.view(np.uint32),
+                                   nbrs.view(np.uint32),
+                                   rows.view(np.uint32)], axis=1)
+            plan = dedup_rows(keys)
+            sel = self._pad_bucket(plan.unique_rows)
+        with span("cf.read.score"):
+            scores, items = jax.device_get(self._score_rec(
+                self.state, jnp.asarray(sims[sel]), jnp.asarray(nbrs[sel]),
+                jnp.asarray(uvec[sel]), n))
 
         dt_ms = (time.perf_counter() - t0) * 1e3
-        for pos, i in enumerate(valid):
-            u = int(plan.scatter[pos])           # fan_out, zipped on host
-            results[i] = [(int(it), float(s))
-                          for s, it in zip(scores[u], items[u])]
-        self._note_query_batch(B, plan.n_unique, plan.savings, dt_ms,
+        with span("cf.read.fanout"):
+            for pos, i in enumerate(valid):
+                u = int(plan.scatter[pos])       # fan_out, zipped on host
+                results[i] = [(int(it), float(s))
+                              for s, it in zip(scores[u], items[u])]
+        self._note_query_batch(B, plan.n_unique, dt_ms,
                                degraded=k_eff != int(k_neighbors))
         return results
 
@@ -1237,38 +1258,45 @@ class CFServer:
         assert len(users) == len(items), (len(users), len(items))
         results = [0.0] * len(users)
         valid = []
-        for i, (u, it) in enumerate(zip(users, items)):
-            if guard.validate_user_id(u, int(self.state.n_active)):
-                self._reject("predict", guard.R_USER_ID, u)
-            elif guard.validate_item_id(it, self.state.n_items):
-                self._reject("predict", guard.R_ITEM_ID, it)
-            else:
-                valid.append(i)
+        with span("cf.read.validate"):
+            for i, (u, it) in enumerate(zip(users, items)):
+                if guard.validate_user_id(u, int(self.state.n_active)):
+                    self._reject("predict", guard.R_USER_ID, u)
+                elif guard.validate_item_id(it, self.state.n_items):
+                    self._reject("predict", guard.R_ITEM_ID, it)
+                else:
+                    valid.append(i)
         if not valid:
             return results
         self._pre_query()
         k_eff = self._query_k(k)
         t0 = time.perf_counter()
 
-        uvec = np.asarray([int(users[i]) for i in valid], np.int32)
-        ivec = np.asarray([int(items[i]) for i in valid], np.int32)
-        sims, nbrs = jax.device_get(self._probe_topk(
-            self.state, jnp.asarray(self._pad_bucket(uvec)), k_eff))
+        with span("cf.read.probe"):
+            uvec = np.asarray([int(users[i]) for i in valid], np.int32)
+            ivec = np.asarray([int(items[i]) for i in valid], np.int32)
+            sims, nbrs = jax.device_get(self._probe_topk(
+                self.state, jnp.asarray(self._pad_bucket(uvec)), k_eff))
         B = len(uvec)
         sims, nbrs = sims[:B], nbrs[:B]
 
-        keys = np.concatenate([sims.view(np.uint32), nbrs.view(np.uint32),
-                               ivec.reshape(-1, 1).view(np.uint32)], axis=1)
-        plan = dedup_rows(keys)
-        sel = self._pad_bucket(plan.unique_rows)
-        preds = jax.device_get(self._score_pred(
-            self.state, jnp.asarray(sims[sel]), jnp.asarray(nbrs[sel]),
-            jnp.asarray(ivec[sel])))
+        with span("cf.read.dedup"):
+            keys = np.concatenate([sims.view(np.uint32),
+                                   nbrs.view(np.uint32),
+                                   ivec.reshape(-1, 1).view(np.uint32)],
+                                  axis=1)
+            plan = dedup_rows(keys)
+            sel = self._pad_bucket(plan.unique_rows)
+        with span("cf.read.score"):
+            preds = jax.device_get(self._score_pred(
+                self.state, jnp.asarray(sims[sel]), jnp.asarray(nbrs[sel]),
+                jnp.asarray(ivec[sel])))
 
         dt_ms = (time.perf_counter() - t0) * 1e3
-        for pos, i in enumerate(valid):
-            results[i] = float(preds[int(plan.scatter[pos])])
-        self._note_query_batch(B, plan.n_unique, plan.savings, dt_ms,
+        with span("cf.read.fanout"):
+            for pos, i in enumerate(valid):
+                results[i] = float(preds[int(plan.scatter[pos])])
+        self._note_query_batch(B, plan.n_unique, dt_ms,
                                degraded=k_eff != int(k))
         return results
 
@@ -1286,11 +1314,12 @@ class CFServer:
 
     def _apply_add_rating(self, user: int, item: int,
                           rating: float) -> None:
-        if self._cache is None:
-            self._cache = self._init_cache(self.state.ratings)
-        self.state, self._cache = self._add(
-            self.state, self._cache, jnp.int32(user), jnp.int32(item),
-            jnp.float32(rating))
+        with span("cf.add_rating.apply"):
+            if self._cache is None:
+                self._cache = self._init_cache(self.state.ratings)
+            self.state, self._cache = self._add(
+                self.state, self._cache, jnp.int32(user), jnp.int32(item),
+                jnp.float32(rating))
         if self.replicas is not None:
             self.replicas.apply_rows([user], self.state)
         if self._plan is not None:

@@ -135,10 +135,11 @@ class TestTwinDedup:
         R[9] = R[3]                          # users 3, 7, 9 are twins
         srv = CFServer(R, ServerConfig(capacity_extra=8))
         users = [3, 7, 9, 3, 1, 9]
+        q0, u0 = srv.stats.queries, srv.stats.query_unique
         out = srv.recommend_batch(users, n=4, k_neighbors=5)
         assert out[0] == out[1] == out[2] == out[3] == out[5]
-        assert srv.stats.query_unique < srv.stats.queries
-        assert srv.stats.query_dedup_savings[-1] > 0
+        assert srv.stats.queries - q0 == 6
+        assert srv.stats.query_unique - u0 < 6         # this batch deduped
 
     def test_dedup_rows_collapses_only_identical(self):
         rows = np.asarray([[1.0, 2.0], [1.0, 2.0], [1.0, 2.5], [1.0, 2.0]],

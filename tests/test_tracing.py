@@ -1,0 +1,126 @@
+"""The server's spans (``serving/tracing.py``) in a profiler trace.
+
+A tiny ``CFServer`` with a WAL, a health sweep after every onboard and
+room for two new users is driven under ``jax.profiler`` through onboards
+(the third rotates the arena), a recommend batch, a predict batch and a
+rating update, each inside a request span as the benchmark opens them.
+The trace, reduced by ``bench/spans.py``, holds every name of ``SPANS``
+inside the request it belongs to, each stage once per call; and the
+traced server answers bit for bit as an untraced one does.
+"""
+from __future__ import annotations
+
+import sys
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax
+
+from repro.serving import CFServer, ServerConfig, SnapshotConfig, WalConfig
+from repro.serving.tracing import SPANS
+
+REPO = Path(__file__).resolve().parents[1]
+if str(REPO) not in sys.path:
+    sys.path.insert(0, str(REPO))
+
+READS = {"recommend_batch", "predict_batch"}
+BELONGS = {"cf.onboard.rotate": {"onboard_user"},
+           "cf.onboard.run": {"onboard_user"},
+           "cf.health_check": {"onboard_user"},
+           "cf.wal.append": {"onboard_user", "add_rating"},
+           "cf.add_rating.apply": {"add_rating"}}
+ONCE_PER_CALL = [("cf.onboard.run", {"onboard_user"}),
+                 ("cf.add_rating.apply", {"add_rating"})] + [
+    (s, READS) for s in SPANS if s.startswith("cf.read.")]
+
+
+def _ratings(n=12, m=10):
+    rng = np.random.default_rng(13)
+    R = (rng.integers(1, 6, (n, m)) * (rng.random((n, m)) < 0.5)
+         ).astype(np.float32)
+    R[R.sum(axis=1) == 0, 0] = 3.0
+    return R
+
+
+def _drive(srv, R):
+    """(request span, answer) of each call, the answers as plain values."""
+    fresh = np.zeros(R.shape[1], np.float32)
+    fresh[[1, 4]] = [5.0, 2.0]
+    calls = [
+        ("onboard_user", lambda: srv.onboard_user(R[3].copy())),
+        ("onboard_user", lambda: srv.onboard_user(fresh)),
+        ("onboard_user", lambda: srv.onboard_user(R[5].copy())),  # rotates
+        ("recommend_batch", lambda: srv.recommend_batch(
+            [0, 3, 12, 5, 3], n=3, k_neighbors=4)),
+        ("predict_batch", lambda: srv.predict_batch(
+            [1, 12, 13], [2, 4, 7], k=4)),
+        ("add_rating", lambda: srv.add_rating(2, 6, 4.0)),
+        ("recommend_batch", lambda: srv.recommend_batch(
+            [2, 7], n=3, k_neighbors=4)),
+    ]
+    out = []
+    for name, call in calls:
+        with jax.profiler.TraceAnnotation(name):
+            ans = call()
+        if name == "onboard_user":
+            ans = (ans.status, ans.user_id, ans.twin_found, ans.rotated)
+        out.append((name, ans))
+    return out
+
+
+def _server(tmp: Path, R):
+    return CFServer(R, ServerConfig(
+        capacity_extra=2, snapshot=SnapshotConfig(every=2, check_every=1),
+        wal=WalConfig(dir=str(tmp))))
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    from bench import spans
+    R = _ratings()
+    tmp = tmp_path_factory.mktemp("tracing")
+    plain = _server(tmp / "wal_plain", R)
+    want = _drive(plain, R)
+    srv = _server(tmp / "wal_traced", R)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp / "trace"), profiler_options=options)
+    try:
+        with jax.profiler.TraceAnnotation("window"):
+            got = _drive(srv, R)
+        jax.block_until_ready(srv.state)
+    finally:
+        jax.profiler.stop_trace()
+    return {"want": want, "got": got, "plain": plain, "srv": srv,
+            "trace": spans.reduce_dir(tmp / "trace")}
+
+
+def test_traced_answers_are_bit_identical(traced):
+    assert traced["got"] == traced["want"]
+    assert [a for n, a in traced["got"] if n == "onboard_user"][2][3], \
+        "the third onboard should rotate the arena"
+    a = jax.tree_util.tree_leaves(traced["plain"].state)
+    b = jax.tree_util.tree_leaves(traced["srv"].state)
+    assert all(np.array_equal(np.asarray(x), np.asarray(y))
+               for x, y in zip(a, b))
+
+
+def test_every_span_inside_its_request(traced):
+    t = traced["trace"]
+    assert {n for n, _, _ in t.program_spans} == set(SPANS)
+    requests = [s for s in t.spans if s[0] not in ("window", "wait")]
+    for name, s, e in t.program_spans:
+        around = [r for r, rs, re in requests if rs <= s and e <= re]
+        assert len(around) == 1, (name, around)
+        assert around[0] in BELONGS.get(name, READS), (name, around)
+
+
+def test_each_stage_once_per_call(traced):
+    t = traced["trace"]
+    for stage, requests in ONCE_PER_CALL:
+        for request, rs, re in t.requests(requests):
+            n = sum(1 for name, s, e in t.program_spans
+                    if name == stage and rs <= s and e <= re)
+            assert n == 1, (stage, request, n)

@@ -60,6 +60,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.types import CFState, SENTINEL, SENTINEL_GATE
 from repro.core.maintenance import merge_new_users_into_base
@@ -115,13 +116,13 @@ def _merge_base_rows(sim_vals: jax.Array, sim_idx: jax.Array, U: jax.Array,
     gi_raw = sim_idx[rows]
     # Gate out any write-region entries (rows refreshed by add_rating
     # already carry them), stable re-sort so the gated lists are ascending
-    # again, then merge the whole burst in one pass.
+    # again, then merge the whole burst in one pass.  The ids ride the
+    # sort as its payload: permuting the lists by per-element gathers
+    # (argsort + take_along_axis) takes a TPU far longer than the sort.
     gate = gi_raw < n_base
     gv = jnp.where(gate, gv_raw, SENTINEL)
     gi = jnp.where(gate, gi_raw, -1)
-    order = jnp.argsort(gv, axis=1, stable=True)
-    gv = jnp.take_along_axis(gv, order, axis=1)
-    gi = jnp.take_along_axis(gi, order, axis=1)
+    gv, gi = lax.sort((gv, gi), dimension=1, is_stable=True, num_keys=1)
     mv, mi = merge_new_users_into_base(gv, gi, U[:, rows], buf_ids,
                                        use_pallas=use_pallas)
     return mv, mi.astype(jnp.int32)
@@ -142,9 +143,8 @@ def _burst_rows(U: jax.Array, *, n_base: int, n_frozen: int,
     W = jnp.full((k, n_new), SENTINEL, jnp.float32)
     W = W.at[:, :n_base].set(U[:, :n_base].astype(jnp.float32))
     W = W.at[:, n_base:n_frozen].set(C.astype(jnp.float32))
-    bi = jnp.argsort(W, axis=1, stable=True).astype(jnp.int32)
-    bv = jnp.take_along_axis(W, bi, axis=1)
-    return bv, bi
+    col = lax.broadcasted_iota(jnp.int32, W.shape, 1)
+    return lax.sort((W, col), dimension=1, is_stable=True, num_keys=1)
 
 
 def rotate_arena_frozen(state: CFState, *, n_base: int, n_frozen: int,

@@ -19,6 +19,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.kernels.list_merge.kernel import merge_insert_pallas
 from repro.kernels.list_merge.ref import NEG_INF, POS_INF
@@ -32,11 +33,11 @@ def _round_up(n: int, mult: int) -> int:
 def _sort_inserts(ins_vals: jax.Array, ins_idx: jax.Array,
                   ins_mask: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Gate masked lanes to NEG_INF and stable-sort each row's inserts
-    ascending — ties keep burst order, masked lanes sort to the front."""
+    ascending — ties keep burst order, masked lanes sort to the front.
+    The indices ride the sort as its payload (no argsort + gathers)."""
     gated = jnp.where(ins_mask, ins_vals, NEG_INF)
-    order = jnp.argsort(gated, axis=1, stable=True)
-    return (jnp.take_along_axis(gated, order, axis=1),
-            jnp.take_along_axis(ins_idx, order, axis=1))
+    return lax.sort((gated, ins_idx), dimension=1, is_stable=True,
+                    num_keys=1)
 
 
 def _merge_xla(vals: jax.Array, idx: jax.Array, sv: jax.Array,

@@ -1,12 +1,14 @@
 """One run of one benchmark cell on the chip.
 
-    python3 -m bench.run --workload douban8k.twin_burst --seed 7 \\
+    python3 -m bench.run --workload douban8k.twin_burst_12 --seed 7 \\
         --seconds 20 --trace 0
 
 The run makes the deployment and its traffic from ``--seed``, builds the
 server, warms up every program the window will run, drives the open-loop
 window, checks what the window produced against the plain reference
-(``bench/reference.py``), and prints one JSON line last on stdout:
+(``bench/reference.py``) and, where the deployment keeps checkpoints on
+disk, against what a restarted server recovers from them and its log,
+and prints one JSON line last on stdout:
 ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end
 metrics with ``--trace 0``, its per-layer metrics with ``--trace 1``),
 ``device`` and, with ``--trace 1``, ``breakdown``; the last key,
@@ -43,7 +45,7 @@ import math  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
-from dataclasses import dataclass, field  # noqa: E402
+from dataclasses import dataclass, field, replace  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 import numpy as np  # noqa: E402
@@ -169,15 +171,19 @@ def fill_cache(root: Path, workload: str, seed: int, seconds: float,
     every arena shape its rotations reach are compiled into the persistent
     cache during set-up and not inside the window; it then leaves a mark
     in the cache, and later runs skip this.  Every seed reaches the same
-    shapes: the onboard count is the same for every seed.  It runs in a
-    process of its own (``_fill_in_child``), so that the run's own
-    process starts as every later run's does, with nothing traced."""
+    shapes: the onboard count is the same for every seed.  Its calls are
+    slow while they compile, so its server's straggler monitor is held
+    off: three slow calls in a row would walk the ladder to another path
+    and leave the window's programs uncompiled.  It runs in a process of
+    its own (``_fill_in_child``), so that the run's own process starts as
+    every later run's does, with nothing traced."""
     cell = spec.load(root, workload, rate)
     device_info(cell.workload["chips"], require_chip)
     configure_jax(cache_dir)
     sys.path.insert(0, str(ROOT / "src"))
     import jax
-    from repro.serving import CFServer
+    from repro.serving import CFServer, LadderConfig
+    from repro.training.elastic import StragglerMonitor
     cfg = cell.config
     R = datagen.synth_ratings(seed, cfg["n_users"], cfg["n_items"],
                               cfg["n_ratings"], cfg["min_per_user"],
@@ -185,7 +191,9 @@ def fill_cache(root: Path, workload: str, seed: int, seconds: float,
     window = traffic.schedule(cell.mix, cfg, R, seed, seconds)
     warm = traffic.warmup(cell.mix, cfg, R, seed, window)
     with tempfile.TemporaryDirectory(prefix="bench_fill_") as tmp:
-        srv = CFServer(R, server_config(cfg, Path(tmp)))
+        quiet = LadderConfig(monitor=StragglerMonitor(
+            window=64, straggler_ratio=math.inf, hang_timeout_s=math.inf))
+        srv = CFServer(R, replace(server_config(cfg, Path(tmp)), ladder=quiet))
         for req in warm + window:
             if req.op == ONBOARD:
                 call(srv, req)
@@ -244,11 +252,13 @@ class Outcome:
 def call(srv, req: traffic.Request) -> tuple[bool, dict]:
     a = req.args
     if req.op == ONBOARD:
+        s0 = srv.stats.snapshots
         res = srv.onboard_user(a["row"])
         return res.ok, {"user_id": res.user_id, "twin_found": res.twin_found,
                         "rotated": res.rotated, "seq": res.seq,
                         "status": res.status, "rung": res.rung,
-                        "n_base": srv.n_base}
+                        "n_base": srv.n_base,
+                        "snapshots": srv.stats.snapshots - s0}
     if req.op == ADD_RATING:
         return srv.add_rating(a["user"], a["item"], a["stars"]), {}
     q0, u0 = srv.stats.queries, srv.stats.query_unique
@@ -262,16 +272,50 @@ def call(srv, req: traffic.Request) -> tuple[bool, dict]:
                 "unique": srv.stats.query_unique - u0}
 
 
+class GcMeter:
+    """Seconds the cyclic garbage collector pauses the process."""
+
+    def __init__(self):
+        self.seconds, self._t = 0.0, 0.0
+
+    def __call__(self, phase: str, _info: dict) -> None:
+        if phase == "start":
+            self._t = time.perf_counter()
+        else:
+            self.seconds += time.perf_counter() - self._t
+
+    def __enter__(self):
+        gc.callbacks.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        gc.callbacks.remove(self)
+
+
+def _usage(gc_meter: GcMeter) -> np.ndarray:
+    import resource
+    u = resource.getrusage(resource.RUSAGE_SELF)
+    return np.asarray([u.ru_utime, u.ru_stime, u.ru_minflt, u.ru_majflt,
+                       u.ru_nvcsw, u.ru_nivcsw, gc_meter.seconds])
+
+
+USAGE = ("user_s", "sys_s", "minor_faults", "major_faults",
+         "voluntary_switches", "involuntary_switches", "gc_s")
+
+
 def drive(srv, window: list[traffic.Request], seconds: float
           ) -> tuple[list[Outcome], list[float], float]:
     """Issue the requests in due order, each when due or, when the server
     is still busy, as soon as it is free.  Returns the outcomes, how late
     the generator woke for each request it slept for, and the window's
-    length."""
+    length.  Each outcome keeps what its call cost the process (CPU
+    seconds, page faults, context switches, garbage-collector pauses) and,
+    after a call that rotated, the device's memory counters, so that a
+    call far slower than its kind can be told apart afterwards."""
     import jax
     out, late = [], []
     t0 = time.perf_counter()
-    with jax.profiler.TraceAnnotation("window"):
+    with jax.profiler.TraceAnnotation("window"), GcMeter() as gcm:
         for req in window:
             now = time.perf_counter() - t0
             if now > seconds + GRACE_S:
@@ -281,12 +325,46 @@ def drive(srv, window: list[traffic.Request], seconds: float
                 with jax.profiler.TraceAnnotation("wait"):
                     time.sleep(req.due - now)
                 late.append(time.perf_counter() - t0 - req.due)
+            u0 = _usage(gcm)
             o = Outcome(req, time.perf_counter() - t0)
             with jax.profiler.TraceAnnotation(SPANS[req.op]):
                 o.ok, o.info = call(srv, req)
             o.end = time.perf_counter() - t0
+            o.info["usage"] = _usage(gcm) - u0
+            if o.info.get("rotated"):
+                o.info["memory"] = jax.devices()[0].memory_stats()
             out.append(o)
     return out, late, time.perf_counter() - t0
+
+
+def slowest_calls(outcomes: list[Outcome], n: int = 3) -> list[dict]:
+    """The ``n`` slowest calls of the window, each with its place, what
+    it did and what it cost the process; beside them the median of the
+    calls of the same kind (rotated, or wrote a checkpoint, or neither)."""
+    def kind(o):
+        return (bool(o.info.get("rotated")), bool(o.info.get("snapshots")))
+
+    def row(o):
+        return {"index": outcomes.index(o), "start_s": o.start,
+                "seconds": o.end - o.start, "op": o.req.op,
+                "rotated": kind(o)[0], "checkpointed": kind(o)[1],
+                **dict(zip(USAGE, o.info["usage"].tolist())),
+                "memory": o.info.get("memory")}
+
+    timed = [o for o in outcomes if "usage" in o.info]
+    slow = sorted(timed, key=lambda o: o.start - o.end)[:n]
+    out = []
+    for o in slow:
+        peers = [p for p in timed if kind(p) == kind(o) and p is not o]
+        r = row(o)
+        if peers:
+            r["peers_median"] = {
+                "n": len(peers),
+                "seconds": float(np.median([p.end - p.start for p in peers])),
+                **dict(zip(USAGE, np.median(
+                    [p.info["usage"] for p in peers], axis=0).tolist()))}
+        out.append(r)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -320,6 +398,33 @@ def collect_onboard(srv, log: list[Outcome], R: np.ndarray, rng) -> dict:
             "idx": idx, "ratings": ratings, "records": records}
 
 
+def collect_recovered(cfg: dict, R: np.ndarray, workdir: Path,
+                      log: list[Outcome], got: dict) -> dict:
+    """What a restarted process loads: ``CFServer.recover`` over the run's
+    checkpoint and log directories, once the live server is dropped, read
+    at the sampled users and at the rating row of every acknowledged
+    onboard whose record the log no longer holds.  A recovery that raises
+    is kept as its error."""
+    from repro.serving import CFServer
+    gone = np.asarray(sorted(o.info["user_id"] for o in log
+                             if o.req.op == ONBOARD and o.ok
+                             and o.info["seq"] not in got["records"]),
+                      np.int64)
+    t = time.perf_counter()
+    try:
+        srv = CFServer.recover(R, server_config(cfg, workdir))
+    except Exception as e:          # noqa: BLE001 — nothing was recovered
+        return {"error": repr(e), "gone": gone}
+    vals, idx, ratings = _fetch_rows(srv, got["users"])
+    out = {"n_base": srv.n_base, "n_active": int(srv.state.n_active),
+           "vals": vals, "idx": idx, "ratings": ratings, "gone": gone,
+           "gone_rows": _fetch_rows(srv, gone)[2],
+           "seconds": time.perf_counter() - t}
+    del srv
+    gc.collect()
+    return out
+
+
 def collect_read(srv, log: list[Outcome], R: np.ndarray, rng) -> dict:
     refreshed = np.unique([o.req.args["user"] for o in log
                            if o.req.op == ADD_RATING and o.ok])
@@ -339,8 +444,11 @@ def check_onboard(cfg: dict, R: np.ndarray, log: list[Outcome], got: dict,
                   rng, control=None) -> tuple[dict, list]:
     """Numbers for an onboarding cell: the sampled rows against exact
     cosines, the sampled twin decisions given the probes the log recorded,
-    and the log's read-back.  ``control`` (a lower-precision cosine)
-    writes the rows in the server's place."""
+    and each acknowledged onboard read back, from the log or, where a
+    checkpoint truncated its record, from the recovered server
+    (``got["recovered"]``, which is also held bit for bit to the live
+    server).  ``control`` (a lower-precision cosine) writes the rows in
+    the server's place."""
     cos = reference.Exact()
     done = sorted((o for o in log if o.req.op == ONBOARD and o.ok),
                   key=lambda o: o.info["user_id"])
@@ -365,11 +473,16 @@ def check_onboard(cfg: dict, R: np.ndarray, log: list[Outcome], got: dict,
                              for k in ("rung", "twin_found")}
                             if w["row"] in by_uid else "base")
     row_hash = np.asarray([hash(row.tobytes()) for row in R_all])
-    picked = [done[j] for j in sorted(rng.choice(
-        len(done), min(SAMPLE["twin_checks"], len(done)), replace=False))]
-    picked = [(o, got["records"].get(o.info["seq"])) for o in picked]
-    picked = [(o, r) for o, r in picked if r is not None
-              and r.fields.get("use_twin") and control is None]
+    # The probes are in the log's records: the twin decisions are drawn
+    # from the onboards whose record a checkpoint has not truncated.
+    records = got["records"]
+    logged = [o for o in done if o.info["seq"] in records]
+    picked = [logged[j] for j in sorted(rng.choice(
+        len(logged), min(SAMPLE["twin_checks"], len(logged)),
+        replace=False))]
+    picked = [(o, records[o.info["seq"]]) for o in picked]
+    picked = [(o, r) for o, r in picked
+              if r.fields.get("use_twin") and control is None]
     if picked:
         probes = np.unique(np.concatenate(
             [r.arrays["probes"] for _, r in picked]).astype(np.int64))
@@ -391,15 +504,59 @@ def check_onboard(cfg: dict, R: np.ndarray, log: list[Outcome], got: dict,
                 notes.append(f"user {uid}: twin_found="
                              f"{o.info['twin_found']}, reference "
                              f"{want_found}")
-    wal_missing = sum(
-        1 for o in done
-        if (r := got["records"].get(o.info["seq"])) is None
-        or not np.array_equal(r.arrays["ratings"],
-                              o.req.args["row"].astype(np.float32)))
+    rec = got.get("recovered")
+    recovered_rows = {}
+    if rec is not None and "error" not in rec:
+        recovered_rows = {u: row for u, row in zip(rec["gone"].tolist(),
+                                                   rec["gone_rows"])
+                          if u < rec["n_active"]}
+    # The newest checkpoint the live server took, by its own counter: a
+    # truncated record has to lie at or below it.
+    checkpoint_seq = max((o.info["seq"] for o in done
+                          if o.info["snapshots"]), default=0)
+
+    def read_back(o) -> bool:
+        row = o.req.args["row"].astype(np.float32)
+        r = records.get(o.info["seq"])
+        if r is not None:
+            return np.array_equal(r.arrays["ratings"], row)
+        uid = o.info["user_id"]
+        return (o.info["seq"] <= checkpoint_seq and uid in recovered_rows
+                and np.array_equal(recovered_rows[uid], row))
+
+    numbers = {"sim_err": rows.sim_err, "unit_err": rows.unit_err,
+               "rows_wrong": rows.rows_wrong, "twin_wrong": twin_wrong,
+               "wal_missing": sum(not read_back(o) for o in done)}
+    if rec is not None:
+        numbers["recovered_wrong"] = _recovered_wrong(got, rec)
+        numbers["wal_truncated"] = int(rec["gone"].size)
+        notes.append(f"recovery: {rec['error']}" if "error" in rec else
+                     f"recovery: {rec['gone'].size} acknowledged onboards "
+                     f"read back from a checkpoint, {len(logged)} from the "
+                     f"log, {len(picked)} twin decisions checked, "
+                     f"{rec['seconds']!r} s")
     notes.append("lists " + json.dumps(rows.summary()))
-    return ({"sim_err": rows.sim_err, "unit_err": rows.unit_err,
-             "rows_wrong": rows.rows_wrong, "twin_wrong": twin_wrong,
-             "wal_missing": wal_missing}, notes)
+    return numbers, notes
+
+
+def _recovered_wrong(got: dict, rec: dict) -> int:
+    """Mismatches of the recovered server against the live one at the
+    window's end: its ``n_active``, its ``n_base``, and each sampled
+    user whose list values, ids or ratings differ in any bit.  A recovery
+    that raised misses all of them."""
+    n = len(got["users"])
+    if "error" in rec:
+        return n + 2
+    wrong = np.zeros(n, bool)
+    for key in ("vals", "idx", "ratings"):
+        a, b = np.asarray(got[key]), np.asarray(rec[key])
+        if a.shape != b.shape or a.dtype != b.dtype:
+            return n + 2
+        wrong |= (np.ascontiguousarray(a).view(np.uint8).reshape(n, -1)
+                  != np.ascontiguousarray(b).view(np.uint8).reshape(n, -1)
+                  ).any(axis=1)
+    return (int(rec["n_active"] != got["n_active"])
+            + int(rec["n_base"] != got["n_base"]) + int(wrong.sum()))
 
 
 class _Replay:
@@ -657,6 +814,10 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
             srv, log, R, rng)
         del srv
         gc.collect()
+        # Where checkpoints truncate the log, a restart has to recover
+        # the acknowledged onboards the log no longer holds.
+        if onboarding and cfg["server"]["snapshot"]["disk"]:
+            got["recovered"] = collect_recovered(cfg, R, Path(tmp), log, got)
 
     # The comparison, once the server is gone.
     lower = None
@@ -700,6 +861,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
             "recommend_unique": [o.info["unique"] for o in outcomes
                                  if o.req.op == RECOMMEND and o.ok],
             "generator_late_ms": _late_summary(late),
+            "slowest_calls": slowest_calls(outcomes),
             "setup_phases_s": phases,
             "stats_delta": {k: stats1[k] - stats0[k] for k in stats0
                             if stats1[k] != stats0[k]},

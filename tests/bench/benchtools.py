@@ -1,8 +1,9 @@
 """Tiny deployments for the benchmark's CPU tests.
 
 ``tiny_root`` writes a benchmark root with one small deployment and a
-cell for each of the repository's traffic mixes; the mixes, the metric
-readers and the peak table are found beside the benchmark (``bench/``).
+cell for each of the repository's traffic mixes, and the same deployment
+checkpointed to disk under ``twin_burst_12``; the mixes, the metric readers
+and the peak table are found beside the benchmark (``bench/``).
 """
 from __future__ import annotations
 
@@ -14,7 +15,10 @@ REPO = Path(__file__).resolve().parents[2]
 if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
 
-MIXES = ("twin_burst", "fresh_onboard", "read_zipf", "read_uniform")
+# The repository's traffic mixes (``bench/traffic/``), by the kind of
+# traffic each sends: the tests' ids.
+MIXES = {"twin_burst": "twin_burst_12", "fresh_onboard": "fresh_onboard_12",
+         "read_zipf": "read_zipf", "read_uniform": "read_uniform"}
 
 
 def tiny_config() -> dict:
@@ -27,7 +31,17 @@ def tiny_config() -> dict:
     # control's over 4.8e-6, 1.1e-5 and 0.06.
     cfg["limits"] = {"sim_err": 1e-6, "unit_err": 2e-6, "rows_wrong": 0,
                      "twin_wrong": 0, "wal_missing": 0, "read_err": 1e-4,
-                     "rec_wrong": 0}
+                     "rec_wrong": 0, "recovered_wrong": 0}
+    return cfg
+
+
+def tiny_durable_config() -> dict:
+    """The tiny deployment checkpointed to disk every 8 onboards, so that
+    a window truncates the log: its onboards are read back through what a
+    restarted server recovers."""
+    cfg = tiny_config()
+    cfg["name"] = "tiny_durable"
+    cfg["server"]["snapshot"].update(every=8, disk=True)
     return cfg
 
 

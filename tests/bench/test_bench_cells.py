@@ -5,10 +5,10 @@ from __future__ import annotations
 
 import pytest
 
-from benchtools import MIXES, run_tiny
+from benchtools import MIXES, run_tiny, tiny_durable_config
 
 
-@pytest.mark.parametrize("mix", MIXES)
+@pytest.mark.parametrize("mix", list(MIXES.values()), ids=list(MIXES))
 def test_cell_runs_and_is_correct(tiny_root, cache_dir, mix):
     res = run_tiny(tiny_root, f"tiny.{mix}", cache_dir)
     assert res["correct"], res["checks"]
@@ -22,13 +22,40 @@ def test_cell_runs_and_is_correct(tiny_root, cache_dir, mix):
         assert check["value"] <= check["limit"]
 
 
-@pytest.mark.parametrize("mix", MIXES)
+@pytest.mark.parametrize("mix", list(MIXES.values()), ids=list(MIXES))
 def test_control_comes_out_incorrect(tiny_root, cache_dir, mix):
     """The reference at bf16_3x in the server's place fails a limit."""
     res = run_tiny(tiny_root, f"tiny.{mix}", cache_dir, control=True)
     assert not res["correct"], res["checks"]
     over = [k for k, c in res["checks"].items() if c["value"] > c["limit"]]
     assert "sim_err" in over or "read_err" in over
+
+
+def test_durable_cell_reads_back_through_recovery(tiny_root, cache_dir):
+    """Checkpoints every 8 onboards truncate the log inside the window;
+    the acknowledged onboards it no longer holds are read back from the
+    server a restart recovers, which matches the live one bit for bit."""
+    from bench import run
+    out = run.run_cell(tiny_root, "tiny_durable.twin_burst_12", 2**31 + 41,
+                       1.5, False, require_chip=False, cache_dir=cache_dir)
+    res, info = out["result"], out["info"]
+    assert res["correct"], res["checks"]
+    assert res["checks"]["wal_missing"]["value"] == 0
+    assert res["checks"]["recovered_wrong"]["value"] == 0
+    assert info["stats_delta"]["snapshots"] >= 1
+    # More onboards were truncated than the warm-up sent before the window.
+    n_warm = tiny_durable_config()["server"]["snapshot"]["check_every"]
+    assert info["readings"]["wal_truncated"] > n_warm
+
+
+def test_durable_control_comes_out_incorrect(tiny_root, cache_dir):
+    """The control fails the deployment checkpointed to disk too: its
+    lists are the reference's at bf16_3x; the restart is the program's."""
+    res = run_tiny(tiny_root, "tiny_durable.twin_burst_12", cache_dir,
+                   control=True)
+    sim_err = res["checks"]["sim_err"]
+    assert not res["correct"] and sim_err["value"] > sim_err["limit"]
+    assert res["checks"]["recovered_wrong"]["value"] == 0
 
 
 def test_same_seed_same_inputs(tiny_root, cache_dir):

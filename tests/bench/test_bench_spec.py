@@ -118,7 +118,7 @@ def test_nothing_compiles_in_the_window(tiny_root, tmp_path, monkeypatch):
         **{**kw, "straggler_ratio": math.inf}))
     cache = tmp_path / "cache"
     for _ in range(2):
-        out = run.run_cell(tiny_root, "tiny.twin_burst", 2**31 + 21, 3.0,
+        out = run.run_cell(tiny_root, "tiny.twin_burst_12", 2**31 + 21, 3.0,
                            False, require_chip=False, cache_dir=cache)
         assert out["result"]["correct"], out["result"]["checks"]
         c = out["info"]["compile_in_window"]

@@ -39,9 +39,10 @@ construction):
     whole write region ``[n_base, n_active)`` now;
   * ``RotationPlan`` — the chunked, resumable rotation: freeze the burst
     boundary at plan start, merge base rows in bounded slices
-    (``step(state, budget_rows)``) while new onboards keep landing past
-    the frozen boundary, then ``finalize(state)`` performs the atomic
-    swap.  Rows onboarded mid-plan are *carried* into the new write
+    (``step(state, budget_rows)``) into accumulators kept on the device
+    while new onboards keep landing past the frozen boundary, then
+    ``finalize(state)`` assembles the new arena in one program: the
+    atomic swap.  Rows onboarded mid-plan are *carried* into the new write
     region unchanged (onboarding only ever writes the new user's own
     row); base rows refreshed mid-plan by ``add_rating`` are re-merged at
     finalize from the live state, and a refresh of a frozen burst row
@@ -54,6 +55,7 @@ construction):
 from __future__ import annotations
 
 import math
+import time
 from functools import partial
 
 import numpy as np
@@ -128,6 +130,25 @@ def _merge_base_rows(sim_vals: jax.Array, sim_idx: jax.Array, U: jax.Array,
     return mv, mi.astype(jnp.int32)
 
 
+@partial(jax.jit, static_argnames=("n_base",))
+def _freeze(sim_vals: jax.Array, sim_idx: jax.Array, buf_ids: jax.Array, *,
+            n_base: int) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """A plan's start, as one program: the burst's recovered rows ``U``
+    and zeroed (n_base, L + k) accumulators for the merged base rows."""
+    width = sim_vals.shape[1] + buf_ids.shape[0]
+    return (unsorted_rows(sim_vals, sim_idx, buf_ids),
+            jnp.zeros((n_base, width), jnp.float32),
+            jnp.zeros((n_base, width), jnp.int32))
+
+
+@partial(jax.jit, donate_argnums=(0, 1))
+def _scatter_rows(acc_v: jax.Array, acc_i: jax.Array, rows: jax.Array,
+                  mv: jax.Array, mi: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Merged rows written into the accumulators (donated) at ``rows``;
+    a repeated row carries the same merged values each time."""
+    return acc_v.at[rows].set(mv.astype(acc_v.dtype)), acc_i.at[rows].set(mi)
+
+
 def _burst_rows(U: jax.Array, *, n_base: int, n_frozen: int,
                 n_new: int) -> tuple[jax.Array, jax.Array]:
     """Full-width sorted lists for the compacted burst rows.
@@ -147,20 +168,15 @@ def _burst_rows(U: jax.Array, *, n_base: int, n_frozen: int,
     return lax.sort((W, col), dimension=1, is_stable=True, num_keys=1)
 
 
-def rotate_arena_frozen(state: CFState, *, n_base: int, n_frozen: int,
-                        extra: int,
-                        use_pallas: bool | None = None) -> CFState:
-    """Compact the frozen burst ``[n_base, n_frozen)`` into a new base
-    arena of capacity ``n_active + extra``; rows ``[n_frozen, n_active)``
-    (onboarded after the boundary froze) are *carried* into the new write
-    region with their lists re-fit to the new width — valid because
-    onboarding only ever writes the new user's own row, so a carried
-    row's list is exactly what onboarding into the new arena would have
-    produced.  ``n_frozen == n_active`` reproduces the classic full
-    rotation.  This is also the deterministic replay of a WAL
-    ``rotate_commit`` record."""
-    n_act = int(state.n_active)
-    k = n_frozen - n_base
+def _assemble(state: CFState, mv: jax.Array | None, mi: jax.Array | None,
+              U: jax.Array | None, *, n_base: int, n_frozen: int,
+              n_act: int, extra: int) -> CFState:
+    """The rotated arena from the live ``state`` and the merged base rows
+    ``(mv, mi)`` (None when the burst is empty): the base rows re-fit to
+    the new width, the burst rows built from ``U``, the rows onboarded
+    after the boundary froze carried, and a fresh write region of
+    ``extra`` slots.  Shared by both modes: run op by op in
+    ``rotate_arena_frozen``, as one program in ``RotationPlan.finalize``."""
     n_new = n_act + extra
     m = state.n_items
     grow = n_new - n_act
@@ -171,15 +187,10 @@ def rotate_arena_frozen(state: CFState, *, n_base: int, n_frozen: int,
     norms = jnp.concatenate([
         state.norms[:n_act], jnp.zeros((grow,), state.norms.dtype)])
 
-    if k == 0:                               # pure growth, nothing to merge
+    if mv is None:                           # pure growth, nothing to merge
         base_v, base_i = _fit_width(state.sim_vals[:n_frozen],
                                     state.sim_idx[:n_frozen], n_new)
     else:
-        buf = jnp.arange(n_base, n_frozen, dtype=jnp.int32)
-        U = unsorted_rows(state.sim_vals, state.sim_idx, buf)    # (k, N)
-        mv, mi = _merge_base_rows(state.sim_vals, state.sim_idx, U,
-                                  jnp.arange(n_base, dtype=jnp.int32), buf,
-                                  n_base=n_base, use_pallas=use_pallas)
         mv, mi = _fit_width(mv, mi, n_new)
         bv, bi = _burst_rows(U, n_base=n_base, n_frozen=n_frozen,
                              n_new=n_new)
@@ -205,6 +216,33 @@ def rotate_arena_frozen(state: CFState, *, n_base: int, n_frozen: int,
         sim_idx=jnp.concatenate(blocks_i + [empty_i], axis=0),
         n_active=jnp.asarray(n_act, jnp.int32),
     )
+
+
+_assemble_jit = jax.jit(
+    _assemble, static_argnames=("n_base", "n_frozen", "n_act", "extra"))
+
+
+def rotate_arena_frozen(state: CFState, *, n_base: int, n_frozen: int,
+                        extra: int,
+                        use_pallas: bool | None = None) -> CFState:
+    """Compact the frozen burst ``[n_base, n_frozen)`` into a new base
+    arena of capacity ``n_active + extra``; rows ``[n_frozen, n_active)``
+    (onboarded after the boundary froze) are *carried* into the new write
+    region with their lists re-fit to the new width — valid because
+    onboarding only ever writes the new user's own row, so a carried
+    row's list is exactly what onboarding into the new arena would have
+    produced.  ``n_frozen == n_active`` reproduces the classic full
+    rotation.  This is also the deterministic replay of a WAL
+    ``rotate_commit`` record."""
+    mv = mi = U = None
+    if n_frozen > n_base:
+        buf = jnp.arange(n_base, n_frozen, dtype=jnp.int32)
+        U = unsorted_rows(state.sim_vals, state.sim_idx, buf)    # (k, N)
+        mv, mi = _merge_base_rows(state.sim_vals, state.sim_idx, U,
+                                  jnp.arange(n_base, dtype=jnp.int32), buf,
+                                  n_base=n_base, use_pallas=use_pallas)
+    return _assemble(state, mv, mi, U, n_base=n_base, n_frozen=n_frozen,
+                     n_act=int(state.n_active), extra=extra)
 
 
 def rotate_arena(state: CFState, *, n_base: int, extra: int,
@@ -233,12 +271,14 @@ class RotationPlan:
 
     Created when the server decides to rotate *ahead* of exhaustion; the
     expensive part — gating + merging every base row — runs in bounded
-    slices (``step``) interleaved with live traffic, and the cheap
+    slices (``step``) interleaved with live traffic, each merge's rows
+    scattered into accumulators kept on the device, and the cheap
     remainder (burst-row construction, carried rows, concatenation) runs
-    once at ``finalize``.  The plan is pure precompute: it never mutates
-    the state it reads, a crash mid-plan loses nothing (nothing is logged
-    until the swap commits), and its output is bit-identical to
-    ``rotate_arena_frozen(live_state, ...)`` at swap time.
+    once at ``finalize``, as one program.  The plan is pure precompute:
+    it never mutates the state it reads, a crash mid-plan loses nothing
+    (nothing is logged until the swap commits), and its output is
+    bit-identical to ``rotate_arena_frozen(live_state, ...)`` at swap
+    time.
 
     Live mutations are reconciled through ``note_write``:
 
@@ -262,8 +302,8 @@ class RotationPlan:
         self.elapsed_ms = 0.0        # accumulated step+finalize time
         self._buf = jnp.arange(self.n_base, self.n_frozen, dtype=jnp.int32)
         self._U: jax.Array | None = None
-        self._mv: np.ndarray | None = None       # (n_base, L + k) host accum
-        self._mi: np.ndarray | None = None
+        self._mv: jax.Array | None = None        # (n_base, L + k) on device
+        self._mi: jax.Array | None = None
         self._cursor = 0
         self._dirty: set[int] = set()
         self._stale = self.k > 0     # U snapshot pending (or invalidated)
@@ -305,36 +345,34 @@ class RotationPlan:
     # -- bounded work -------------------------------------------------------
 
     def _refreeze(self, state: CFState) -> None:
-        self._U = unsorted_rows(state.sim_vals, state.sim_idx, self._buf)
-        L = state.sim_vals.shape[1]
-        self._mv = np.empty((self.n_base, L + self.k), np.float32)
-        self._mi = np.empty((self.n_base, L + self.k), np.int32)
+        self._U, self._mv, self._mi = _freeze(
+            state.sim_vals, state.sim_idx, self._buf, n_base=self.n_base)
         self._cursor = 0
         self._dirty.clear()
         self._stale = False
 
     def _run_rows(self, state: CFState, rows: np.ndarray) -> None:
-        """One fixed-shape merge dispatch over ``rows`` (padded by
-        repetition to the chunk width; pad lanes recompute a row already
-        done — harmless, row-local, discarded by the scatter)."""
+        """One fixed-shape merge over ``rows`` (padded by repetition to
+        the chunk width; pad lanes recompute a row already in the batch —
+        row-local, so they write the same values), scattered into the
+        accumulators."""
         n = rows.shape[0]
         if n < self.chunk:
             rows = np.concatenate(
                 [rows, np.full(self.chunk - n, rows[-1], rows.dtype)])
+        rows = jnp.asarray(rows, jnp.int32)
         mv, mi = _merge_base_rows(state.sim_vals, state.sim_idx, self._U,
-                                  jnp.asarray(rows, jnp.int32), self._buf,
-                                  n_base=self.n_base,
+                                  rows, self._buf, n_base=self.n_base,
                                   use_pallas=self.use_pallas)
-        self._mv[rows[:n]] = np.asarray(mv)[:n]
-        self._mi[rows[:n]] = np.asarray(mi)[:n]
+        self._mv, self._mi = _scatter_rows(self._mv, self._mi, rows, mv, mi)
 
     def step(self, state: CFState, budget_rows: int) -> int:
         """Merge up to ``budget_rows`` base rows against the frozen block;
-        returns the number of rows actually processed.  Never mutates
-        ``state``; safe to call at any point between server mutations."""
+        returns the number of rows actually processed, once their merge
+        has finished on the device.  Never mutates ``state``; safe to call
+        at any point between server mutations."""
         if self.k == 0 or self.done:
             return 0
-        import time
         t0 = time.perf_counter()
         if self._stale:
             self._refreeze(state)
@@ -352,6 +390,7 @@ class RotationPlan:
             self._run_rows(state, np.asarray(batch))
             self._dirty.difference_update(batch)
             processed += len(batch)
+        jax.block_until_ready((self._mv, self._mi))
         self.elapsed_ms += (time.perf_counter() - t0) * 1e3
         return processed
 
@@ -359,51 +398,15 @@ class RotationPlan:
 
     def finalize(self, state: CFState) -> CFState:
         """Produce the rotated state from the live ``state``: drain any
-        remaining/dirty rows, build the burst + carried blocks, and
-        assemble the new arena.  Bit-identical to
+        remaining/dirty rows, then build the burst + carried blocks and
+        assemble the new arena in one program.  Bit-identical to
         ``rotate_arena_frozen(state, n_base=.., n_frozen=.., extra=..)``."""
         while not self.done:                     # force-drain the tail
             self.step(state, self.n_base)
-        import time
         t0 = time.perf_counter()
-        n_act = int(state.n_active)
-        n_new = n_act + self.extra
-        m = state.n_items
-        grow = n_new - n_act
-
-        ratings = jnp.concatenate([
-            state.ratings[:n_act],
-            jnp.zeros((grow, m), state.ratings.dtype)], axis=0)
-        norms = jnp.concatenate([
-            state.norms[:n_act], jnp.zeros((grow,), state.norms.dtype)])
-
-        if self.k == 0:
-            base_v, base_i = _fit_width(state.sim_vals[:self.n_frozen],
-                                        state.sim_idx[:self.n_frozen], n_new)
-        else:
-            mv, mi = _fit_width(jnp.asarray(self._mv),
-                                jnp.asarray(self._mi), n_new)
-            bv, bi = _burst_rows(self._U, n_base=self.n_base,
-                                 n_frozen=self.n_frozen, n_new=n_new)
-            base_v = jnp.concatenate([mv.astype(jnp.float32), bv], axis=0)
-            base_i = jnp.concatenate([mi, bi], axis=0)
-
-        blocks_v, blocks_i = [base_v], [base_i]
-        if n_act > self.n_frozen:
-            cv, ci = _fit_width(state.sim_vals[self.n_frozen:n_act],
-                                state.sim_idx[self.n_frozen:n_act], n_new)
-            blocks_v.append(cv.astype(jnp.float32))
-            blocks_i.append(ci)
-
-        empty_v = jnp.full((grow, n_new), SENTINEL, jnp.float32)
-        empty_i = jnp.broadcast_to(jnp.arange(n_new, dtype=jnp.int32),
-                                   (grow, n_new))
-        out = CFState(
-            ratings=ratings,
-            norms=norms,
-            sim_vals=jnp.concatenate(blocks_v + [empty_v], axis=0),
-            sim_idx=jnp.concatenate(blocks_i + [empty_i], axis=0),
-            n_active=jnp.asarray(n_act, jnp.int32),
-        )
+        out = _assemble_jit(state, self._mv, self._mi, self._U,
+                            n_base=self.n_base, n_frozen=self.n_frozen,
+                            n_act=int(state.n_active), extra=self.extra)
+        self._mv = self._mi = None               # the swap consumed them
         self.elapsed_ms += (time.perf_counter() - t0) * 1e3
         return out

@@ -29,10 +29,12 @@ the historical flat kwargs still work via a deprecation shim.
 With ``RotationConfig.budget_rows > 0`` arena rotation is *incremental*:
 a ``RotationPlan`` starts when free write slots fall to ``reserve_slots``
 and merges at most ``budget_rows`` base rows per onboard/tick (plus retry
-backoff waits and shed backpressure windows), while new users keep
-landing in the buffer past the frozen boundary; the final atomic swap is
-bit-identical to the synchronous rotation of the live state and is the
-only part a request ever waits for (``ServerStats.rotation_pause_ms``).
+backoff waits and shed backpressure windows; span ``cf.rotation.step``,
+``ServerStats.plan_steps`` / ``plan_step_s``), while new users keep
+landing in the buffer past the frozen boundary; the final atomic swap
+(span ``cf.rotation.swap``) is bit-identical to the synchronous rotation
+of the live state and is the stall one request pays
+(``ServerStats.rotation_pause_s`` / ``rotation_pause_ms``).
 The swap is WAL-logged as ``rotate_commit`` (frozen boundary + growth),
 so recovery replays it deterministically via ``rotate_arena_frozen``.
 
@@ -181,6 +183,9 @@ class ServerStats:
     wal_replayed: int = 0
     plan_restarts: int = 0      # incremental-rotation precompute restarts
     forced_drains: int = 0      # buffer filled before the plan finished
+    plan_steps: int = 0         # incremental-rotation slices run
+    plan_step_s: float = 0.0    # seconds in those slices, device included
+    rotation_pause_s: float = 0.0   # seconds requests stalled for rotations
     queries: int = 0            # query rows served (valid rows only)
     query_batches: int = 0      # recommend_batch / predict_batch calls
     query_unique: int = 0       # rows actually scored after twin dedup
@@ -195,9 +200,16 @@ class ServerStats:
         # Fixed-size ring buffers: sustained traffic must not grow host
         # memory; summary() percentiles are over the trailing window.
         self.onboard_ms = deque(maxlen=self.latency_window)
+        # Each rotation's work.  Synchronous: the merge and the new arena's
+        # assembly, all of it inside the onboard that found the arena full
+        # (the re-wrap of the programs excluded).  Incremental: the plan's
+        # slices and its swap-time assembly summed, spread over the
+        # onboards the slices ran in, so not a stall.
         self.rotation_ms = deque(maxlen=64)
         # What rotation actually cost a *single request*: the synchronous
-        # stall (full rotation, or just the final swap when incremental).
+        # stall (full rotation, or just the final swap when incremental;
+        # a forced drain's remaining slices count in it), the same seconds
+        # ``rotation_pause_s`` sums.
         self.rotation_pause_ms = deque(maxlen=64)
         # Per-batch query latency (the trailing-window view).
         self.query_ms = deque(maxlen=self.latency_window)
@@ -225,6 +237,9 @@ class ServerStats:
             "wal_replayed": self.wal_replayed,
             "plan_restarts": self.plan_restarts,
             "forced_drains": self.forced_drains,
+            "plan_steps": self.plan_steps,
+            "plan_step_s": self.plan_step_s,
+            "rotation_pause_s": self.rotation_pause_s,
             "onboard_p50_ms": ms[len(ms) // 2],
             "onboard_p99_ms": ms[min(len(ms) - 1, int(len(ms) * 0.99))],
             "rotation_p50_ms": rot[len(rot) // 2],
@@ -260,7 +275,7 @@ class OnboardResult:
     status: str = "ok"        # ok|rejected|shed|error|rolled_back
     rung: str = "twinsearch"  # ladder level the request was served at
     latency_ms: float = 0.0
-    rotated: bool = False     # this request triggered/absorbed a rotation
+    rotated: bool = False     # a rotation (or its swap) ran in this call
     seq: int = -1             # WAL sequence number (-1: nothing logged)
     twin_found: bool = False
     reason: str | None = None
@@ -608,8 +623,6 @@ class CFServer:
         self._build_jits()
         self.stats.rotations += 1
         self.stats.rotation_ms.append(dt_ms)
-        # Synchronous rotation: the triggering request stalls for all of it.
-        self.stats.rotation_pause_ms.append(dt_ms)
         if self.replicas is not None:
             self.replicas.reset(self.state)
         log.info("arena rotated: capacity %d -> %d (n_base=%d, %.1fms)",
@@ -634,24 +647,37 @@ class CFServer:
         log.info("incremental rotation started: n_base=%d burst=%d "
                  "extra=%d", self.n_base, k0, extra)
 
-    def _maintenance_tick(self, budget_rows: int | None = None) -> None:
+    def _maintenance_tick(self, budget_rows: int | None = None, *,
+                          stall: bool = True) -> bool:
         """Advance background rotation by one bounded slice and swap when
-        the plan completes.  Called at safe points only — between mutating
-        ops, never inside one (the in-flight op's closures captured the
-        pre-swap state)."""
+        the plan completes; True iff it swapped.  Called at safe points
+        only — between mutating ops, never inside one (the in-flight op's
+        closures captured the pre-swap state).  ``stall``: a request waits
+        for this tick, so a swap counts as its rotation pause."""
         if self._rcfg.budget_rows <= 0:
-            return
+            return False
         if self._plan is None:
             if self.k_cap <= 0 or self._free_slots() > self._reserve_slots():
-                return
+                return False
             self._start_plan()
         budget = (int(budget_rows) if budget_rows is not None
                   else self._rcfg.budget_rows)
         if not self._plan.done:
-            self._plan.step(self.state, budget)
+            self._plan_step(budget)
             self._crashpoint("rotation.step")
         if self._plan.done:
-            self._swap_rotation()
+            self._swap_rotation(stall=stall)
+            return True
+        return False
+
+    def _plan_step(self, budget_rows: int) -> None:
+        """One slice of the pending plan, counted in ``plan_steps`` and
+        ``plan_step_s``."""
+        t0 = time.perf_counter()
+        with span("cf.rotation.step"):
+            self._plan.step(self.state, budget_rows)
+        self.stats.plan_steps += 1
+        self.stats.plan_step_s += time.perf_counter() - t0
 
     def _drain_during_wait(self, delay_s: float) -> None:
         """Retry-backoff hook: spend otherwise-idle wait time on rotation
@@ -659,47 +685,62 @@ class CFServer:
         ``run`` closure captured the pre-swap state."""
         if (self._plan is not None and not self._plan.done
                 and self._rcfg.budget_rows > 0):
-            self._plan.step(self.state, self._rcfg.budget_rows)
+            self._plan_step(self._rcfg.budget_rows)
 
     def _force_drain(self) -> None:
         """The buffer filled before the plan finished (or before it even
         started): finish the rotation now, synchronously.  Degrades to
         exactly the old stall in the worst case — never worse."""
+        t0 = time.perf_counter()
         if self._plan is None:
             self._start_plan()
         else:
             self.stats.forced_drains += 1
         while not self._plan.done:
             self._plan.step(self.state, max(1, self.n_base))
-        self._swap_rotation()
+        self._swap_rotation(drained_s=time.perf_counter() - t0)
 
-    def _swap_rotation(self) -> None:
+    def _note_pause(self, seconds: float) -> None:
+        """A rotation's stall on the request path."""
+        self.stats.rotation_pause_s += seconds
+        self.stats.rotation_pause_ms.append(seconds * 1e3)
+
+    def _swap_rotation(self, drained_s: float = 0.0,
+                       stall: bool = True) -> None:
         """The atomic swap: log ``rotate_commit``, finalize the plan from
         the live state (bit-identical to ``rotate_arena_frozen``), and
-        retarget geometry.  The WAL record carries the frozen boundary so
-        recovery replays the swap deterministically at the same point in
-        the op stream."""
+        retarget geometry.  The WAL record carries the frozen boundary and
+        the growth so recovery replays the swap deterministically at the
+        same point in the op stream.  ``drained_s``: seconds a forced drain
+        already stalled this request for; ``stall`` false (an idle-time
+        swap) leaves the pause counters alone."""
         plan = self._plan
         old_capacity = self.state.capacity
+        # The rows carried past the frozen boundary take their slots out of
+        # the growth, so the new write region keeps the plan's size.
+        carried = int(self.state.n_active) - plan.n_frozen
+        plan.extra = max(1, plan.extra - carried)
         t0 = time.perf_counter()
-        self._log("rotate_commit", fields={"n_base": plan.n_base,
-                                           "n_frozen": plan.n_frozen,
-                                           "extra": plan.extra})
-        self._crashpoint("rotation.commit_post_wal")
-        new_state = plan.finalize(self.state)
-        new_state.sim_vals.block_until_ready()
-        pause_ms = (time.perf_counter() - t0) * 1e3
-        self._install_rotated(new_state, n_base=plan.n_frozen)
+        with span("cf.rotation.swap"):
+            self._log("rotate_commit", fields={"n_base": plan.n_base,
+                                               "n_frozen": plan.n_frozen,
+                                               "extra": plan.extra})
+            self._crashpoint("rotation.commit_post_wal")
+            new_state = plan.finalize(self.state)
+            new_state.sim_vals.block_until_ready()
+            self._install_rotated(new_state, n_base=plan.n_frozen)
+        pause_s = drained_s + time.perf_counter() - t0
         self._plan = None
         self.stats.rotations += 1
         self.stats.rotation_ms.append(plan.elapsed_ms)
-        self.stats.rotation_pause_ms.append(pause_ms)
+        if stall:
+            self._note_pause(pause_s)
         self.stats.plan_restarts += plan.restarts
         self._crashpoint("rotation.post_swap")
         log.info("arena rotated (incremental): capacity %d -> %d "
                  "(n_base=%d, %.1fms total, %.1fms pause)", old_capacity,
                  self.state.capacity, self.n_base, plan.elapsed_ms,
-                 pause_ms)
+                 pause_s * 1e3)
 
     def _install_rotated(self, new_state: CFState, *, n_base: int) -> None:
         """Point the server at a rotated arena (live swap or WAL replay)."""
@@ -716,8 +757,9 @@ class CFServer:
         pending incremental rotation (defaults to the configured
         per-onboard budget).  Wire this into idle-period hooks — e.g. the
         ladder's ``StragglerMonitor`` quiet windows — so rotations finish
-        between bursts instead of inside them."""
-        self._maintenance_tick(budget_rows)
+        between bursts instead of inside them.  A swap made here stalls no
+        request, so the pause counters leave it out."""
+        self._maintenance_tick(budget_rows, stall=False)
         plan = self._plan
         return {"active": plan is not None,
                 "remaining_rows": plan.remaining_rows if plan else 0,
@@ -1061,21 +1103,21 @@ class CFServer:
         if self.level == LEVEL_SHED:
             if self._clock() < self._shed_until:
                 self.stats.shed += 1
-                if self._lcfg.drain_on_shed:
-                    # Backpressure time is free maintenance time.
-                    self._maintenance_tick()
+                # Backpressure time is free maintenance time.
+                swapped = (self._lcfg.drain_on_shed
+                           and self._maintenance_tick())
                 return OnboardResult(
                     status="shed", rung=LEVEL_NAMES[self.level],
+                    rotated=swapped,
                     retry_after_s=self._shed_until - self._clock())
             # Cooldown expired: probe the cheaper build path again.
             self._set_level(LEVEL_DEGRADED if self._replicas_degraded()
                             else LEVEL_TRADITIONAL)
 
         # Background rotation tick: a safe point (no op in flight).
-        self._maintenance_tick()
+        rotated = self._maintenance_tick()
 
         self._crashpoint("onboard.pre_wal")
-        rotated = False
         if int(self.state.n_active) >= self.state.capacity:
             rotated = True
             with span("cf.onboard.rotate"):
@@ -1083,9 +1125,11 @@ class CFServer:
                     # The plan didn't finish (or start) in time: drain it.
                     self._force_drain()
                 else:
+                    t0 = time.perf_counter()
                     self._log("rotate")
                     self._crashpoint("rotate.post_wal")
                     self._rotate()
+                    self._note_pause(time.perf_counter() - t0)
 
         r0_np = np.asarray(ratings, dtype=np.float32)
         r0 = jnp.asarray(r0_np)

@@ -47,7 +47,13 @@ class RotationConfig:
     switches to the chunked plan: rotation starts when free write slots
     drop to ``reserve_slots`` and each onboard/tick merges at most
     ``budget_rows`` base rows, with the atomic swap deferred until the
-    plan completes (or the buffer truly fills, which force-drains)."""
+    plan completes (or the buffer truly fills, which force-drains).
+
+    Budget rule: a plan must finish within ``reserve_slots`` onboards, so
+    ``budget_rows`` is at least ``n_base / reserve_slots`` at the largest
+    ``n_base`` the deployment reaches, rounded up.  Below that, the onboard
+    that finds the write region full drains the rest synchronously and
+    ``ServerStats.forced_drains`` counts it."""
     headroom: float = 1.0
     budget_rows: int = 0
     reserve_slots: int | None = None   # None -> max(1, k_cap // 4)

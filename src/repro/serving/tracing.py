@@ -27,6 +27,12 @@ SPANS = (
     "cf.read.score",        # the score program and its transfer to the host
     "cf.read.fanout",       # the answers built on the host
     "cf.add_rating.apply",  # cache init, scalar conversions, the dispatch
+    "cf.rotation.step",     # one slice of an incremental rotation: the
+                            # merge's dispatch, its device time, the rows
+                            # written into the plan's accumulators
+    "cf.rotation.swap",     # an incremental rotation's swap: the
+                            # rotate_commit record, the last of the plan,
+                            # the new arena installed, the programs re-wrapped
 )
 
 

@@ -13,7 +13,9 @@ similarity math.  All faults come from the deterministic harness in
 """
 from __future__ import annotations
 
+import math
 import os
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -22,15 +24,16 @@ import jax.numpy as jnp
 
 from repro.core import (RotationPlan, rotate_arena, rotate_arena_frozen,
                         unsorted_rows)
+from repro.core.reference import cosine_vs_all_np
 from repro.core.similarity import cosine_matrix
 from repro.core.types import SENTINEL_GATE
 from repro.distributed import (ReplicaState, ReplicatedArena,
                                ReplicationConfig)
 from repro.kernels.verify_rows.ops import arena_healthy, rows_sorted_finite
 from repro.kernels.verify_rows.ref import rows_sorted_finite_ref
-from repro.serving import (CFServer, RotationConfig, ServerConfig,
-                           ServerStats, SnapshotConfig, WalConfig,
-                           WriteAheadLog,
+from repro.serving import (CFServer, LadderConfig, RotationConfig,
+                           ServerConfig, ServerStats, SnapshotConfig,
+                           WalConfig, WriteAheadLog,
                            LEVEL_DEGRADED, LEVEL_SHED, LEVEL_TRADITIONAL,
                            LEVEL_TWINSEARCH)
 from repro.serving.guard import RetryPolicy
@@ -42,7 +45,7 @@ from repro.testing import (CRASH_POINTS, ROTATION_CRASH_POINTS, FakeClock,
                            poison_state)
 from repro.training import checkpoint
 from repro.training.elastic import Action, StragglerMonitor
-from tests.conftest import make_ratings
+from tests.conftest import TEST_SEED, make_ratings
 
 pytestmark = pytest.mark.faults
 
@@ -972,6 +975,71 @@ class TestRotationHysteresis:
 # Incremental (chunked, resumable) rotation — ISSUE 9 tentpole
 # ---------------------------------------------------------------------------
 
+# Floods through incremental rotation: name -> (base users, items,
+# capacity_extra, budget_rows, onboards, stream).  "tiny" is the original
+# pool of copies and fresh rows; the others are a small Douban-like
+# catalogue, where a budget of 64 rows finishes each plan within the
+# reserve of 4 slots (4 x 64 >= n_base) and 16 does not ("starved").
+FLOODS = {
+    "tiny": (24, 12, 4, 6, 12, "pool"),
+    "copy_of_base": (200, 500, 16, 64, 56, "copy_of_base"),
+    "fresh": (200, 500, 16, 64, 56, "fresh"),
+    "starved": (200, 500, 16, 16, 56, "copy_of_base"),
+}
+
+
+def _flood_rows(R, stream: str, count: int, rng) -> np.ndarray:
+    """``count`` onboard rows: the tiny pool, copies of uniformly drawn
+    base users, or fresh profiles."""
+    if stream == "pool":
+        fresh = make_ratings(np.random.default_rng(77), n=6, m=R.shape[1])
+        pool = np.concatenate([R[:4], fresh, R[8:12]], axis=0)
+        return pool[np.arange(count) % len(pool)]
+    if stream == "copy_of_base":
+        return R[rng.integers(0, R.shape[0], count)].copy()
+    return make_ratings(rng, n=count, m=R.shape[1], density=0.05)
+
+
+@pytest.fixture(scope="module")
+def flood(tmp_path_factory):
+    """``flood(case)``: a synchronous server and an incremental one (with
+    a WAL) fed the same onboards, made once per case.  Both hold their
+    straggler monitors off: on a loaded host the calls that compile could
+    walk one server's ladder to the traditional path, and the two would
+    no longer run the same program."""
+    runs = {}
+
+    def quiet():
+        return LadderConfig(monitor=StragglerMonitor(
+            window=64, straggler_ratio=math.inf, hang_timeout_s=math.inf))
+
+    def run(case):
+        if case not in runs:
+            n, m, extra, budget, count, stream = FLOODS[case]
+            rng = np.random.default_rng(TEST_SEED)
+            R = make_ratings(rng, n=n, m=m,
+                             density=0.3 if stream == "pool" else 0.05)
+            rows = _flood_rows(R, stream, count, rng)
+            cfg = ServerConfig(
+                capacity_extra=extra, c_probes=4,
+                wal=WalConfig(dir=str(tmp_path_factory.mktemp(case))),
+                rotation=RotationConfig(budget_rows=budget), ladder=quiet())
+            sync = CFServer(R, ServerConfig(capacity_extra=extra,
+                                            c_probes=4, ladder=quiet()))
+            inc = CFServer(R, cfg)
+            rotated = []
+            for r in rows:
+                assert sync.onboard_user(r).ok
+                res = inc.onboard_user(r)
+                assert res.ok
+                rotated.append(res.rotated)
+            runs[case] = SimpleNamespace(R=R, rows=rows, cfg=cfg, sync=sync,
+                                         inc=inc, rotated=rotated)
+        return runs[case]
+
+    return run
+
+
 class TestIncrementalRotation:
     def _flooded(self, rng, *, n=24, m=12, onboards=4):
         """A server whose write region holds ``onboards`` burst rows."""
@@ -1040,23 +1108,33 @@ class TestIncrementalRotation:
         assert int(out.n_active) == int(srv.state.n_active)
         assert out.capacity == int(srv.state.n_active) + 6
 
-    def test_incremental_flood_matches_synchronous(self, rng):
+    @pytest.mark.parametrize("case", list(FLOODS))
+    def test_incremental_flood_matches_synchronous(self, flood, case):
         """The double-flood oracle, incremental edition: a server rotating
         in budget_rows slices and a synchronously-rotating server end a
         pure onboard flood with bit-identical materialized similarity
-        blocks (geometry may differ — content must not)."""
-        R = make_ratings(rng, n=24, m=12)
-        fresh = make_ratings(np.random.default_rng(77), n=6, m=12)
-        pool = np.concatenate([R[:4], fresh, R[8:12]], axis=0)
-
-        sync = CFServer(R, ServerConfig(capacity_extra=4, c_probes=4))
-        inc = CFServer(R, ServerConfig(
-            capacity_extra=4, c_probes=4,
-            rotation=RotationConfig(budget_rows=6)))
-        for i in range(12):
-            assert sync.onboard_user(pool[i % len(pool)]).ok
-            assert inc.onboard_user(pool[i % len(pool)]).ok
-        assert inc.stats.rotations >= 1
+        blocks (geometry may differ — content must not), and the write
+        region keeps its configured size through the swaps.  At the small
+        Douban-like size the flood runs through three swaps or more, none
+        forced while the budget finishes a plan within the reserve, and
+        each swap marks the onboard it ran in as rotated.  There the two
+        servers' write regions differ in width, and TwinSearch computes a
+        copied user's cosines to the write region over that width, where
+        the CPU backend may round the division in the last bit: between
+        two onboarded users the entries agree to 2 ulps, every other entry
+        bit for bit."""
+        run = flood(case)
+        sync, inc = run.sync, run.inc
+        assert inc.stats.rotations >= (1 if case == "tiny" else 3)
+        assert sum(run.rotated) == inc.stats.rotations
+        # the rows carried through each swap take their slots out of the
+        # growth: the write region keeps its configured size
+        assert inc.k_cap == sync.k_cap == FLOODS[case][2]
+        if case == "starved":
+            assert inc.stats.forced_drains > 0
+        elif case != "tiny":
+            assert inc.stats.forced_drains == 0
+            assert inc.stats.plan_steps > 0
 
         def materialized(srv):
             st = rotate_arena(srv.state, n_base=srv.n_base, extra=0)
@@ -1066,7 +1144,54 @@ class TestIncrementalRotation:
         u_sync, r_sync = materialized(sync)
         u_inc, r_inc = materialized(inc)
         np.testing.assert_array_equal(r_sync, r_inc)
-        np.testing.assert_array_equal(u_sync, u_inc)
+        if case == "tiny":
+            np.testing.assert_array_equal(u_sync, u_inc)
+            return
+        n0 = run.R.shape[0]
+        np.testing.assert_array_equal(u_sync[:n0], u_inc[:n0])
+        np.testing.assert_array_equal(u_sync[:, :n0], u_inc[:, :n0])
+        np.testing.assert_array_max_ulp(u_sync[n0:, n0:], u_inc[n0:, n0:],
+                                        maxulp=2)
+
+    @pytest.mark.parametrize("case", ["copy_of_base", "fresh", "starved"])
+    def test_incremental_rows_are_exact_lists(self, flood, case):
+        """After the swaps every row is the exact list: the rating row as
+        sent, then ascending cosines (float64 NumPy reference) to each
+        user live when the row was written — every base user for a base
+        row, the users before it for a row in the write region — each id
+        once, SENTINEL in the other slots."""
+        run = flood(case)
+        st, n_base = run.inc.state, run.inc.n_base
+        n_act = int(st.n_active)
+        R_all = np.concatenate([run.R, run.rows], axis=0)
+        np.testing.assert_array_equal(np.asarray(st.ratings[:n_act]), R_all)
+        vals = np.asarray(st.sim_vals[:n_act])
+        idx = np.asarray(st.sim_idx[:n_act])
+        width = vals.shape[1]
+        for u in range(n_act):
+            live = n_base if u < n_base else u
+            head = width - live
+            v, i = vals[u, head:], idx[u, head:]
+            assert (vals[u, :head] <= SENTINEL_GATE).all(), u
+            np.testing.assert_array_equal(np.sort(i), np.arange(live))
+            assert (np.diff(v) >= 0).all(), u
+            want = cosine_vs_all_np(R_all[:live], R_all[u])[i]
+            np.testing.assert_allclose(v, want, rtol=0, atol=4e-7,
+                                       err_msg=f"row {u}")
+
+    @pytest.mark.parametrize("case", ["copy_of_base", "fresh"])
+    def test_incremental_recovery_across_swaps(self, flood, case):
+        """``CFServer.recover`` from the WAL alone replays every onboard
+        and each ``rotate_commit`` at its point in the stream: the
+        recovered arena is the live one, bit for bit."""
+        run = flood(case)
+        commits = [r for r in run.inc.wal.records()
+                   if r.op == "rotate_commit"]
+        assert len(commits) == run.inc.stats.rotations >= 3
+        recovered = CFServer.recover(run.R, run.cfg)
+        _assert_states_equal(recovered.state, run.inc.state)
+        assert recovered.n_base == run.inc.n_base
+        assert recovered.state.capacity == run.inc.state.capacity
 
     def test_step_maintenance_drains_between_bursts(self, rng):
         """Quiet-period ticks finish the rotation so no onboard ever pays
@@ -1088,10 +1213,23 @@ class TestIncrementalRotation:
         assert srv.stats.rotations == 1
         assert srv.stats.forced_drains == 0
         assert prog["free_slots"] > 2              # swap re-opened the arena
-        # and the pause the swap charged is recorded separately from the
-        # total rotation work
+        # the idle-time swap stalled no request: its work is in
+        # rotation_ms, not in the pause counters
+        assert len(srv.stats.rotation_ms) == 1
+        assert len(srv.stats.rotation_pause_ms) == 0
+        assert srv.stats.rotation_pause_s == 0.0
+        # the next rotation swaps on the request path, and only its pause
+        # is recorded, separately from the total rotation work
+        i = 4
+        while srv.stats.rotations < 2:
+            assert srv.onboard_user(R[i % 24]).ok
+            i += 1
+            assert i < 100
+        assert len(srv.stats.rotation_ms) == 2
         assert len(srv.stats.rotation_pause_ms) == 1
         assert srv.stats.summary()["rotation_pause_max_ms"] > 0.0
+        assert srv.stats.rotation_pause_s * 1e3 == pytest.approx(
+            srv.stats.rotation_pause_ms[0])
 
     def test_rotation_ms_still_tracks_rotations(self, rng):
         R = make_ratings(rng, n=20, m=10)
